@@ -736,14 +736,6 @@ def main() -> None:
     if merged.get("error"):
         result["error"] = (result.get("error", "") + "; " +
                            merged["error"]).strip("; ")
-
-    if result["value"] is not None:
-        try:
-            from seaweedfs_tpu.stats import metrics
-            if metrics.HAVE_PROMETHEUS:
-                metrics.EC_THROUGHPUT.set(result["value"])
-        except Exception:  # noqa: BLE001
-            pass
     print(json.dumps(result))
     if result["value"] is None:
         sys.exit(1)

@@ -31,6 +31,8 @@ from __future__ import annotations
 
 import numpy as np
 
+from ..util import tracing
+
 # windows gathered per transform dispatch: enough to amortise dispatch
 # latency into noise, small enough that python-side window bookkeeping
 # stays trivial. The REQUESTED width; the resident-memory ceiling is
@@ -95,7 +97,13 @@ def transform_block_async(encoder, coeff: np.ndarray, block: np.ndarray,
     add_stat(stats, dispatches=1, batches=1, windows=int(block.shape[0]),
              bytes_in=int(block.nbytes))
     out = encoder.transform_batch(coeff, block)
-    return lambda: np.asarray(out)
+
+    def readback() -> np.ndarray:
+        with tracing.start("ec", "readback") as sp:
+            got = np.asarray(out)
+            sp.nbytes = got.nbytes
+        return got
+    return readback
 
 
 def verify_block(encoder, block: np.ndarray,

@@ -22,7 +22,7 @@ from typing import Callable
 from ..storage import types as t
 from ..storage.needle import Needle
 from ..storage.needle_map import SortedFileNeedleMap
-from ..util import failpoints, glog
+from ..util import failpoints, glog, tracing
 from . import gf
 from .locate import (LARGE_BLOCK_SIZE, SMALL_BLOCK_SIZE, Interval,
                      locate_data)
@@ -286,7 +286,6 @@ class EcVolume:
         cached under the same keyspace, so recovering a second lost
         shard of the same stripe re-uses the bytes already moved
         instead of re-fetching them."""
-        from ..util import tracing
         rc = self._recover_cache
         key = (self.vid, want_sid, offset, size)
         gen = None
@@ -310,20 +309,24 @@ class EcVolume:
             k = gf.DATA_SHARDS
             got: dict[int, bytes] = {}
             stale_local: list[int] = []
-            for sid in plan.local:
-                if len(got) >= k:
-                    break
-                if sid == want_sid:
-                    continue
-                f = self.shards.get(sid)
-                if f is None:
-                    # unmounted between planning and this read: the
-                    # shard may now live on a peer — demote it to a
-                    # remote candidate instead of dropping the row
-                    stale_local.append(sid)
-                    continue
-                raw = os.pread(f.fileno(), size, offset)
-                got[sid] = raw + b"\x00" * (size - len(raw))
+            with tracing.start("ec", "pread") as rd:
+                nread = 0
+                for sid in plan.local:
+                    if len(got) >= k:
+                        break
+                    if sid == want_sid:
+                        continue
+                    f = self.shards.get(sid)
+                    if f is None:
+                        # unmounted between planning and this read: the
+                        # shard may now live on a peer — demote it to a
+                        # remote candidate instead of dropping the row
+                        stale_local.append(sid)
+                        continue
+                    raw = os.pread(f.fileno(), size, offset)
+                    nread += len(raw)
+                    got[sid] = raw + b"\x00" * (size - len(raw))
+                rd.nbytes = nread
             n_local = len(got)
             # cached survivor intervals: bytes a previous recover of
             # ANOTHER lost shard already moved — free the second time
@@ -584,8 +587,10 @@ class EcVolume:
 
     def read_needle(self, needle_id: int, cookie: int | None = None) -> Needle:
         """Locate via .ecx, gather stripe intervals, parse + CRC-check
-        (ReadEcShardNeedle, store_ec.go:119-153)."""
-        with self._lock:
+        (ReadEcShardNeedle, store_ec.go:119-153). The one volume lock is
+        timed as two `ec` spans: `lock_wait` until it is held, `locked`
+        while it is."""
+        with tracing.timed_lock(self._lock, "ec"):
             offset, size = self.find_needle(needle_id)
             if size == t.TOMBSTONE_FILE_SIZE:
                 raise NotFoundError(f"needle {needle_id:x} deleted")

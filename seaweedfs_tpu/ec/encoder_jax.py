@@ -28,7 +28,12 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..util import tracing
 from . import gf
+
+# name scope of the jitted GF transforms' device ops, those that
+# JaxEncoder.transform_batch dispatches among them
+TRANSFORM_SCOPE = "ec_transform_batch"
 
 
 def _apply_bitplanes(consts: np.ndarray, data: jax.Array) -> jax.Array:
@@ -67,20 +72,20 @@ def _apply_bitplanes(consts: np.ndarray, data: jax.Array) -> jax.Array:
 
 @functools.lru_cache(maxsize=256)
 def _compiled_transform(coeff_key: bytes, rows: int, k: int, use_pallas: bool):
-    """jit-compiled transform for a fixed coefficient matrix."""
+    """jit-compiled transform for a fixed coefficient matrix; its device
+    ops carry TRANSFORM_SCOPE in their op_name metadata."""
     coeff = np.frombuffer(coeff_key, dtype=np.uint8).reshape(rows, k)
     consts = gf.bitplane_constants(coeff)
-
     if use_pallas:
         from ..ops.gf256_pallas import gf256_matmul_pallas
-
-        @jax.jit
-        def fn(data):
-            return gf256_matmul_pallas(consts, data)
+        body = gf256_matmul_pallas
     else:
-        @jax.jit
-        def fn(data):
-            return _apply_bitplanes(consts, data)
+        body = _apply_bitplanes
+
+    @jax.jit
+    def fn(data):
+        with jax.named_scope(TRANSFORM_SCOPE):
+            return body(consts, data)
     return fn
 
 
@@ -95,7 +100,11 @@ def _compiled_batch_transform(coeff_key: bytes, rows: int, k: int):
     with zero cross-device traffic (the transform is per-window)."""
     coeff = np.frombuffer(coeff_key, dtype=np.uint8).reshape(rows, k)
     consts = gf.bitplane_constants(coeff)
-    return jax.jit(jax.vmap(lambda d: _apply_bitplanes(consts, d)))
+
+    def fn(d):
+        with jax.named_scope(TRANSFORM_SCOPE):
+            return _apply_bitplanes(consts, d)
+    return jax.jit(jax.vmap(fn))
 
 
 @functools.lru_cache(maxsize=8)
@@ -167,20 +176,39 @@ class JaxEncoder:
         On the Pallas path the batch folds into the byte axis instead
         (the transform is columnwise, and the explicit-DMA kernel
         already tiles the stream); the vmapped XLA path is the one that
-        shards along the batch dim on a multi-device mesh."""
+        shards along the batch dim on a multi-device mesh.
+
+        The host stages are `ec`/`h2d` (placing the block on the device)
+        and `ec`/`launch` (enqueueing the layout ops and the transform).
+        The jitted transform's device ops run under the name scope
+        TRANSFORM_SCOPE; the eager transposes around the Pallas call
+        carry none (a scope reaches only ops traced inside a jit)."""
         coeff = np.ascontiguousarray(coeff, dtype=np.uint8)
         rows, k = coeff.shape
         use_pallas = self.use_pallas
         if use_pallas is None:
             use_pallas = _default_use_pallas()
         if use_pallas:
-            block = jnp.asarray(block, jnp.uint8)
-            bsz, k2, n = block.shape
-            flat = block.transpose(1, 0, 2).reshape(k2, bsz * n)
-            out = apply_transform(coeff, flat, True)
-            return out.reshape(rows, bsz, n).transpose(1, 0, 2)
+            with tracing.start("ec", "h2d") as sp:
+                # a block already on the device moves nothing
+                if not isinstance(block, jax.Array):
+                    sp.nbytes = block.nbytes
+                block = jnp.asarray(block, jnp.uint8)
+            with tracing.start("ec", "launch") as sp:
+                bsz, k2, n = block.shape
+                flat = block.transpose(1, 0, 2).reshape(k2, bsz * n)
+                out = apply_transform(coeff, flat, True)
+                out = out.reshape(rows, bsz, n).transpose(1, 0, 2)
+                sp.nbytes = block.nbytes
+            return out
         fn = _compiled_batch_transform(coeff.tobytes(), rows, k)
-        return fn(shard_along_batch(np.asarray(block, np.uint8)))
+        with tracing.start("ec", "h2d") as sp:
+            block = shard_along_batch(np.asarray(block, np.uint8))
+            sp.nbytes = block.nbytes
+        with tracing.start("ec", "launch") as sp:
+            out = fn(block)
+            sp.nbytes = block.nbytes
+        return out
 
     def encode_batch(self, block) -> jax.Array:
         """(B, k, L) data windows -> (B, k+m, L) full shard windows."""

@@ -12,10 +12,17 @@ unit is much larger: 1 MB windows gathered eight at a time by the
 stripe-batch engine (ec/batch.py) — 8 MB per shard per dispatch, the same
 DMA-bound payload as the pre-batching 8 MB buffer, at an in-flight block
 the resident-byte budget can hold.
+
+Each host stage of the served paths is an `ec` span (util/tracing.py):
+`pread` (input file reads), `h2d` (host staging and the enqueued
+transfer), `launch` (host time to enqueue the device work), `readback`
+(host blocked on the device and the copy back) and `write` (output file
+writes), each carrying the bytes it moved.
 """
 
 from __future__ import annotations
 
+import contextvars
 import os
 import queue
 import threading
@@ -24,6 +31,7 @@ import numpy as np
 
 from ..storage import types as t
 from ..storage.needle_map import walk_index_blob, write_sorted_index
+from ..util import tracing
 from . import gf
 from .batch import (DEFAULT_BATCH_WINDOWS, add_stat, clamp_batch_windows,
                     transform_block_async, window_blocks)
@@ -115,24 +123,32 @@ def _transform_buffers_async(encoder, coeff: np.ndarray,
     overlaps nothing: its 256KB loop at ec_encoder.go:114-186 is serial).
     CPU encoders compute eagerly and the thunk is a no-op."""
     if _use_overlap(encoder):  # the single async-dispatch predicate
-        import os
-
         import jax
         from ..ops.gf256_pallas import (bytes_to_words, gf256_words_transform,
                                         words_to_bytes)
         n = len(buffers[0])
-        words = [jax.device_put(bytes_to_words(b)) for b in buffers]
-        if os.environ.get("SWTPU_EC_METHOD") == "mxu":
-            # MXU GF(2) bit-matrix formulation (ops/gf256_mxu.py); the
-            # default VPU Pallas kernel wins at small sizes, the MXU at
-            # large streams — bench.py races both
-            from ..ops.gf256_mxu import mxu_words_transform
-            outs = mxu_words_transform(np.asarray(coeff, np.uint8), words)
-        else:
-            consts = gf.bitplane_constants(coeff)
-            outs = gf256_words_transform(consts, words)
-        return lambda: [words_to_bytes(np.asarray(o), n).copy()
+        with tracing.start("ec", "h2d") as sp:
+            words = [jax.device_put(bytes_to_words(b)) for b in buffers]
+            sp.nbytes = n * len(buffers)
+        with tracing.start("ec", "launch") as sp:
+            if os.environ.get("SWTPU_EC_METHOD") == "mxu":
+                # MXU GF(2) bit-matrix formulation (ops/gf256_mxu.py); the
+                # default VPU Pallas kernel wins at small sizes, the MXU
+                # at large streams — bench.py races both
+                from ..ops.gf256_mxu import mxu_words_transform
+                outs = mxu_words_transform(np.asarray(coeff, np.uint8),
+                                           words)
+            else:
+                consts = gf.bitplane_constants(coeff)
+                outs = gf256_words_transform(consts, words)
+            sp.nbytes = n * len(buffers)
+
+        def readback() -> list[np.ndarray]:
+            with tracing.start("ec", "readback") as sp:
+                sp.nbytes = n * len(outs)
+                return [words_to_bytes(np.asarray(o), n).copy()
                         for o in outs]
+        return readback
     # CPU path: native AVX2 kernel when built, numpy table lookup otherwise
     from .encoder_cpu import CpuEncoder
     if isinstance(encoder, CpuEncoder):
@@ -173,6 +189,27 @@ def _iter_row_batches(dat_size: int, large_block: int, small_block: int,
             yield processed, small_block, buf, b
         processed += small_block * gf.DATA_SHARDS
         remaining -= small_block * gf.DATA_SHARDS
+
+
+def _close_outputs(files) -> None:
+    """Close written shard files inside an `ec`/`write` span: a close
+    can block on write-back of what the writes left in the page cache
+    (network filesystems flush on close), which is output cost too."""
+    with tracing.start("ec", "write"):
+        for f in files:
+            f.close()
+
+
+def _count_encoded(nbytes: int) -> None:
+    """SeaweedFS_ec_encode_bytes_total: data bytes handed to the parity
+    transform, counted where both encode paths (`encode_volume` and the
+    served `write_ec_files_batched`) stage a launch, on any backend."""
+    try:
+        from ..stats import metrics
+    except ImportError:
+        return
+    if metrics.HAVE_PROMETHEUS:
+        metrics.EC_ENCODE_BYTES.inc(int(nbytes))
 
 
 def _use_overlap(encoder) -> bool:
@@ -241,8 +278,12 @@ def _run_overlapped(read_batches, launch, write_result,
                 errs.append(e)
                 draining = True  # keep consuming so the caller never blocks
 
-    rt = threading.Thread(target=reader, daemon=True)
-    wt = threading.Thread(target=writer, daemon=True)
+    # each thread runs in a copy of the caller's context, so the stage
+    # spans it opens parent under the caller's span
+    rt = threading.Thread(target=contextvars.copy_context().run,
+                          args=(reader,), daemon=True)
+    wt = threading.Thread(target=contextvars.copy_context().run,
+                          args=(writer,), daemon=True)
     rt.start()
     wt.start()
     try:
@@ -320,26 +361,29 @@ def encode_volume(base_name: str, encoder=None,
         position (no second joined-bytes copy is kept alive)."""
         buf = group[0][2]
         block = np.empty((len(group), gf.DATA_SHARDS, buf), np.uint8)
-        preads = 0
-        for i in range(gf.DATA_SHARDS):
-            runs: list[list[int]] = []
-            for start, bs, _, b in group:
-                off = start + bs * i + b * buf
-                if runs and off == runs[-1][0] + runs[-1][1]:
-                    runs[-1][1] += buf
-                else:
-                    runs.append([off, buf])
-            w = 0
-            for off, ln in runs:
-                f.seek(off)
-                raw = f.read(ln)
-                if len(raw) < ln:
-                    raw += b"\x00" * (ln - len(raw))
-                n = ln // buf
-                block[w:w + n, i, :] = np.frombuffer(
-                    raw, np.uint8).reshape(n, buf)
-                w += n
-            preads += len(runs)
+        preads = got = 0
+        with tracing.start("ec", "pread") as sp:
+            for i in range(gf.DATA_SHARDS):
+                runs: list[list[int]] = []
+                for start, bs, _, b in group:
+                    off = start + bs * i + b * buf
+                    if runs and off == runs[-1][0] + runs[-1][1]:
+                        runs[-1][1] += buf
+                    else:
+                        runs.append([off, buf])
+                w = 0
+                for off, ln in runs:
+                    f.seek(off)
+                    raw = f.read(ln)
+                    got += len(raw)
+                    if len(raw) < ln:
+                        raw += b"\x00" * (ln - len(raw))
+                    n = ln // buf
+                    block[w:w + n, i, :] = np.frombuffer(
+                        raw, np.uint8).reshape(n, buf)
+                    w += n
+                preads += len(runs)
+            sp.nbytes = got
         return block, preads
 
     def batches():
@@ -349,31 +393,26 @@ def encode_volume(base_name: str, encoder=None,
     def launch(item):
         block, preads = item
         add_stat(stats, preads=preads, bytes_read=int(block.nbytes))
-        thunk = transform_block_async(encoder, parity, block, stats)
-        try:
-            from ..stats import metrics
-            if metrics.HAVE_PROMETHEUS:
-                metrics.EC_ENCODE_BYTES.inc(int(block.nbytes))
-        except ImportError:
-            pass
-        return item, thunk
+        _count_encoded(block.nbytes)
+        return item, transform_block_async(encoder, parity, block, stats)
 
     def write_result(item, thunk):
         block, _ = item
         parities = thunk()      # (B, m, buf)
-        for i in range(gf.DATA_SHARDS):
-            outs[i].write(np.ascontiguousarray(block[:, i, :]).tobytes())
-        for p in range(gf.PARITY_SHARDS):
-            outs[gf.DATA_SHARDS + p].write(
-                np.ascontiguousarray(parities[:, p, :]).tobytes())
+        with tracing.start("ec", "write") as sp:
+            for i in range(gf.DATA_SHARDS):
+                outs[i].write(np.ascontiguousarray(block[:, i, :]).tobytes())
+            for p in range(gf.PARITY_SHARDS):
+                outs[gf.DATA_SHARDS + p].write(
+                    np.ascontiguousarray(parities[:, p, :]).tobytes())
+            sp.nbytes = block.nbytes + parities.nbytes
 
     try:
         _run_overlapped(batches(), launch, write_result,
                         overlap=_use_overlap(encoder))
     finally:
         f.close()
-        for o in outs:
-            o.close()
+        _close_outputs(outs)
 
 
 def write_ec_files(base_name: str, encoder=None,
@@ -426,20 +465,23 @@ def write_ec_files_batched(base_names: list[str], encoder=None,
         # their 14 shard files open (a 64-volume rack batch would
         # otherwise hold ~900 fds past the default 1024 soft limit)
         if base in fully_enqueued and pending_refs.get(base, 0) == 0:
-            for f in outs.pop(base, []):
-                f.close()
+            _close_outputs(outs.pop(base, []))
 
     def drain_one() -> None:
         group, thunk = inflight.popleft()
         parities = thunk()
-        off = 0
-        for buffers, base, shard_off in group:
-            ln = len(buffers[0])
-            for p, pbuf in enumerate(parities):
-                f = outs[base][gf.DATA_SHARDS + p]
-                f.seek(shard_off)
-                f.write(np.asarray(pbuf[off:off + ln], np.uint8).tobytes())
-            off += ln
+        with tracing.start("ec", "write") as sp:
+            off = 0
+            for buffers, base, shard_off in group:
+                ln = len(buffers[0])
+                for p, pbuf in enumerate(parities):
+                    f = outs[base][gf.DATA_SHARDS + p]
+                    f.seek(shard_off)
+                    f.write(np.asarray(pbuf[off:off + ln],
+                                       np.uint8).tobytes())
+                off += ln
+            sp.nbytes = off * len(parities)
+        for _, base, _ in group:
             pending_refs[base] -= 1
             maybe_close(base)
 
@@ -447,9 +489,13 @@ def write_ec_files_batched(base_names: list[str], encoder=None,
         group = pending.pop(buf_len, [])
         if not group:
             return
-        cat = [np.concatenate([g[0][i] for g in group])
-               if len(group) > 1 else group[0][0][i]
-               for i in range(gf.DATA_SHARDS)]
+        # joining each shard position's buffers is host staging too; the
+        # transfer's own span carries the bytes
+        with tracing.start("ec", "h2d"):
+            cat = [np.concatenate([g[0][i] for g in group])
+                   if len(group) > 1 else group[0][0][i]
+                   for i in range(gf.DATA_SHARDS)]
+        _count_encoded(len(cat[0]) * gf.DATA_SHARDS)
         inflight.append(
             (group, _transform_buffers_async(encoder, parity, cat)))
         while len(inflight) > _PIPE_DEPTH:
@@ -465,14 +511,18 @@ def write_ec_files_batched(base_names: list[str], encoder=None,
             with open(dat_path, "rb") as f:
                 for start, block_size, buf, b in _iter_row_batches(
                         dat_size, large_block, small_block, buffer_size):
-                    buffers = []
-                    for i in range(gf.DATA_SHARDS):
-                        f.seek(start + block_size * i + b * buf)
-                        raw = f.read(buf)
-                        if len(raw) < buf:
-                            raw += b"\x00" * (buf - len(raw))
-                        buffers.append(np.frombuffer(raw, np.uint8))
-                        outs[base][i].write(raw)
+                    raws = []
+                    with tracing.start("ec", "pread") as sp:
+                        for i in range(gf.DATA_SHARDS):
+                            f.seek(start + block_size * i + b * buf)
+                            raws.append(f.read(buf))
+                        sp.nbytes = sum(map(len, raws))
+                    raws = [raw + b"\x00" * (buf - len(raw)) for raw in raws]
+                    with tracing.start("ec", "write") as sp:
+                        for i, raw in enumerate(raws):
+                            outs[base][i].write(raw)
+                        sp.nbytes = buf * gf.DATA_SHARDS
+                    buffers = [np.frombuffer(raw, np.uint8) for raw in raws]
                     pending.setdefault(buf, []).append(
                         (buffers, base, shard_pos))
                     pending_refs[base] = pending_refs.get(base, 0) + 1
@@ -529,18 +579,21 @@ def _rebuild_rows(base_name: str, encoder, targets: list[int],
         for wi, count in window_blocks(n_windows, batch_windows):
             pos = wi * buffer_size
             take = min(count * buffer_size, shard_size - pos)
-            rows = []
-            for f in ins:
-                f.seek(pos)
-                raw = f.read(take)
-                # zero-pad the tail to whole windows: GF of zero rows
-                # is zero, and the pad is sliced off before writing
-                if len(raw) < count * buffer_size:
-                    raw += b"\x00" * (count * buffer_size - len(raw))
-                rows.append(np.frombuffer(raw, np.uint8
-                                          ).reshape(count, buffer_size))
+            raws = []
+            with tracing.start("ec", "pread") as sp:
+                for f in ins:
+                    f.seek(pos)
+                    raws.append(f.read(take))
+                sp.nbytes = sum(map(len, raws))
+                # zero-pad the tail to whole windows: GF of zero rows is
+                # zero, and the pad is sliced off before writing
+                whole = count * buffer_size
+                block = np.stack(
+                    [np.frombuffer(raw + b"\x00" * (whole - len(raw)),
+                                   np.uint8).reshape(count, buffer_size)
+                     for raw in raws], axis=1)
             add_stat(stats, preads=len(ins), bytes_read=take * len(ins))
-            yield np.stack(rows, axis=1), take
+            yield block, take
 
     def launch(item):
         block, take = item
@@ -551,11 +604,13 @@ def _rebuild_rows(base_name: str, encoder, targets: list[int],
     def write_result(item, thunk):
         block, take = item
         rebuilt = thunk()       # (B, targets, buf)
-        for j, o in enumerate(outs):
-            out = np.ascontiguousarray(rebuilt[:, j, :]
-                                       ).tobytes()[:take]
-            add_stat(stats, bytes_rebuilt=len(out))
-            o.write(out)
+        with tracing.start("ec", "write") as sp:
+            for j, o in enumerate(outs):
+                out = np.ascontiguousarray(rebuilt[:, j, :]
+                                           ).tobytes()[:take]
+                add_stat(stats, bytes_rebuilt=len(out))
+                o.write(out)
+            sp.nbytes = take * len(outs)
 
     try:
         _run_overlapped(batches(), launch, write_result,
@@ -563,8 +618,7 @@ def _rebuild_rows(base_name: str, encoder, targets: list[int],
     finally:
         for f in ins:
             f.close()
-        for o in outs:
-            o.close()
+        _close_outputs(outs)
 
 
 def rebuild_ec_files(base_name: str, encoder=None,

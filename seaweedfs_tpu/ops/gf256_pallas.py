@@ -41,6 +41,9 @@ _LANES = 128
 # bm=256 -> 128 KiB/shard-block, 1.25 MiB input block for k=10.
 _DEFAULT_BM = 256
 _BLOCK_BYTES = _LANES * 4
+# the kernel's name in compiled programs and device traces, whichever
+# call builds it
+KERNEL_NAME = "gf256_transform"
 
 
 def _interpret_default() -> bool:
@@ -98,6 +101,7 @@ def _build_call(consts_key: bytes, rows: int, k: int, wm: int, bm: int,
         in_specs=[spec] * k,
         out_specs=[spec] * rows,
         interpret=interpret,
+        name=KERNEL_NAME,
     )
 
 
@@ -151,6 +155,7 @@ def _build_stacked_call(consts_key: bytes, rows: int, k: int, b: int,
         out_specs=pl.BlockSpec((1, rows, bm, _LANES),
                                lambda v, i: (v, 0, i, 0)),
         interpret=interpret,
+        name=KERNEL_NAME,
     )
 
 

@@ -75,9 +75,6 @@ if HAVE_PROMETHEUS:
     EC_ENCODE_BYTES = Counter(
         "SeaweedFS_ec_encode_bytes_total", "bytes erasure-encoded",
         registry=REGISTRY)
-    EC_THROUGHPUT = Gauge(
-        "SeaweedFS_ec_encode_GBps", "last measured EC encode GB/s/chip",
-        registry=REGISTRY)
     # tiered read caches (util/chunk_cache.py): one label per cache —
     # "needle" (volume hot needles), "chunk" (filer/s3/webdav whole
     # chunks), "ec_recover" (degraded-read reconstructions),

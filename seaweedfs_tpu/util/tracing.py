@@ -37,13 +37,20 @@ Design constraints honored here:
   `SeaweedFS_request_duration_seconds{tier,op,status}` histogram, so
   the trace ring and Prometheus agree by construction;
 - entry spans slower than `-trace.slowms` emit one glog WARNING line
-  carrying the trace id, the grep-able bridge from logs to traces.
+  carrying the trace id, the grep-able bridge from logs to traces;
+- every recorded span is also a host event named `<tier>.<op>` in the
+  JAX profiler's trace while a profiler session runs, on the clock of
+  the device ops, so a device-idle stretch can be read against the
+  program stage that held the host. This module never imports JAX: the
+  event is written only where the process already has.
 """
 
 from __future__ import annotations
 
+import contextlib
 import contextvars
 import random
+import sys
 import threading
 import time
 from collections import deque
@@ -82,6 +89,27 @@ _current: contextvars.ContextVar["Span | None"] = contextvars.ContextVar(
 # is a lock + dict lookup in prometheus_client; spans are hot)
 _hist: object = None
 _hist_children: dict = {}
+
+# jax.profiler.TraceAnnotation, bound on the first span after the process
+# imported JAX (None until then)
+_annotation_cls = None
+
+
+def _annotation(name: str):
+    """An open host event `name` in the JAX profiler's trace, or None:
+    only while a profiler session records, and only in a process that
+    already imported JAX. Without a session it costs one is_enabled()
+    call."""
+    global _annotation_cls
+    cls = _annotation_cls
+    if cls is None:
+        jax = sys.modules.get("jax")
+        cls = getattr(getattr(jax, "profiler", None), "TraceAnnotation",
+                      None)
+        if cls is None:   # no JAX here, or JAX still importing
+            return None
+        _annotation_cls = cls
+    return cls(name) if cls.is_enabled() else None
 
 
 def init(sample: float = 1.0, slow_ms: float = 0.0,
@@ -194,10 +222,10 @@ _NOOP = _NoopSpan()
 class Span:
     __slots__ = ("trace", "span_id", "parent", "tier", "op", "status",
                  "nbytes", "attrs", "events", "t0", "wall0", "dur",
-                 "entry", "_token", "_done", "_discard")
+                 "entry", "_token", "_done", "_discard", "_event")
 
     def __init__(self, trace: str, parent: str, tier: str, op: str,
-                 entry: bool, attrs: dict | None):
+                 entry: bool, attrs: dict | None, t0: float | None = None):
         self.trace = trace
         self.span_id = "%016x" % random.getrandbits(64)
         self.parent = parent
@@ -209,6 +237,15 @@ class Span:
         self.events: list | None = None
         self.t0 = time.perf_counter()
         self.wall0 = time.time()
+        if t0 is None:
+            # the profiler event opens with t0, so it covers what `dur`
+            # covers, for spans finished without being entered too
+            self._event = _annotation(f"{tier}.{op}")
+        else:
+            # a stretch the caller timed (and wrote its profiler event)
+            self.wall0 -= self.t0 - t0
+            self.t0 = t0
+            self._event = None
         self.dur = 0.0
         self.entry = entry
         self._token = None
@@ -272,15 +309,26 @@ class Span:
         into the full handler, which records its own span)."""
         self._discard = True
         self._done = True
+        self._close_event()
         with _lock:
             _inflight.pop(id(self), None)
 
+    def _close_event(self) -> None:
+        ev, self._event = self._event, None
+        if ev is not None:
+            ev.__exit__(None, None, None)
+
     def finish(self, status: str | None = None,
-               nbytes: int | None = None) -> None:
+               nbytes: int | None = None, end: float | None = None) -> None:
+        """Record the span; `end` is a perf_counter() reading taken
+        earlier, where the span ended before it could be recorded."""
         if self._done:
             return
         self._done = True
-        self.dur = (time.perf_counter() - self.t0) * 1000.0
+        if end is None:
+            end = time.perf_counter()
+        self.dur = (end - self.t0) * 1000.0
+        self._close_event()
         if status is not None:
             self.status = status
         elif self.status is None:
@@ -322,6 +370,41 @@ def start(tier: str, op: str, **attrs):
         return _NOOP
     return Span(parent.trace, parent.span_id, tier, op, False,
                 attrs or None)
+
+
+@contextlib.contextmanager
+def timed_lock(lock, tier: str):
+    """Hold `lock` for the block, timing the wait and the hold as child
+    spans `<tier>`/`lock_wait` and `<tier>`/`locked` of the active span.
+    Both are recorded after the release: while the lock is held only
+    clock readings are taken (and, under a profiler session, the
+    profiler events), so threads queued on a hot lock do not queue on
+    the span ring's lock as well. Untraced, it is `with lock`."""
+    parent = _current.get()
+    if not parent:
+        with lock:
+            yield
+        return
+    t0 = time.perf_counter()
+    t1 = t2 = None
+    waiting = _annotation(f"{tier}.lock_wait")
+    try:
+        with lock:
+            t1 = time.perf_counter()
+            if waiting is not None:
+                waiting.__exit__(None, None, None)
+            holding = _annotation(f"{tier}.locked")
+            try:
+                yield
+            finally:
+                t2 = time.perf_counter()
+                if holding is not None:
+                    holding.__exit__(None, None, None)
+    finally:
+        for op, a, b in (("lock_wait", t0, t1), ("locked", t1, t2)):
+            if b is not None:
+                Span(parent.trace, parent.span_id, tier, op, False, None,
+                     t0=a).finish(end=b)
 
 
 def start_root(tier: str, op: str, headers=None,
@@ -581,11 +664,21 @@ async def run_in_executor(fn, *args):
     """run_in_executor that carries the tracing context into the
     worker thread (asyncio does NOT propagate contextvars there), so
     store/EC spans parent under the request span; pays the context
-    copy only while a trace is active."""
+    copy only while a trace is active. Under a trace the hop is timed
+    too: an `exec`/`queue` span runs from the submit to the moment `fn`
+    starts in a worker thread."""
     import asyncio
     loop = asyncio.get_running_loop()
     if _current.get():
         ctx = contextvars.copy_context()
-        return await loop.run_in_executor(None,
-                                          lambda: ctx.run(fn, *args))
+        queued = start("exec", "queue")
+
+        def run():
+            queued.finish()
+            return fn(*args)
+        try:
+            return await loop.run_in_executor(None, lambda: ctx.run(run))
+        finally:
+            if not queued._done:   # cancelled before a thread took it
+                queued.cancel()
     return await loop.run_in_executor(None, lambda: fn(*args))

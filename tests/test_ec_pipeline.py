@@ -10,6 +10,7 @@ import hashlib
 import itertools
 import os
 import random
+import time
 
 import numpy as np
 import pytest
@@ -392,3 +393,206 @@ def test_workers_refuse_a_tpu_backend(tmp_path):
     assert proc.returncode != 0
     assert "R1" in proc.stderr and "one chip" in proc.stderr
     assert not any(p.name.startswith("w") for p in tmp_path.iterdir())
+
+
+# ---------------------------------------------------------------------------
+# stage spans of the served EC paths (util/tracing.py)
+
+STAGES = ("pread", "h2d", "launch", "readback", "write")
+
+
+def _ec_spans(root) -> "dict[str, list[dict]]":
+    """The `ec` spans of root's trace by op; each must parent to root."""
+    from seaweedfs_tpu.util import tracing
+    spans = tracing.trace_spans_dict(root.trace)["spans"]
+    by_op: dict = {}
+    for s in spans:
+        if s["tier"] == "ec":
+            assert s["parent"] == root.span_id, s
+            by_op.setdefault(s["op"], []).append(s)
+    return by_op
+
+
+@pytest.fixture
+def fresh_tracing():
+    from seaweedfs_tpu.util import tracing
+    tracing.init(sample=1.0, slow_ms=0.0)
+    tracing.reset()
+    yield tracing
+    tracing.reset()
+
+
+def _dat_files(tmp_path, sizes, seed=29):
+    rng = np.random.default_rng(seed)
+    bases = []
+    for i, size in enumerate(sizes):
+        base = str(tmp_path / f"v{i}")
+        with open(base + ".dat", "wb") as f:
+            f.write(rng.integers(0, 256, size, dtype=np.uint8).tobytes())
+        bases.append(base)
+    return bases
+
+
+def test_batched_encode_stage_spans(tmp_path, fresh_tracing):
+    """write_ec_files_batched with a JaxEncoder under a request span:
+    every stage is a child span of the request, the pread spans add up
+    to the .dat bytes, the write spans to the 14 shard files, and the
+    byte counter counts the served path."""
+    from seaweedfs_tpu.ec.encoder_jax import JaxEncoder
+    from seaweedfs_tpu.stats import metrics
+    bases = _dat_files(tmp_path, [3 * SB * 10 + 77, 2 * SB * 10])
+    before = (metrics.EC_ENCODE_BYTES._value.get()
+              if metrics.HAVE_PROMETHEUS else 0)
+    with fresh_tracing.start_root("volume", "ec.generate_batch") as root:
+        pl.write_ec_files_batched(bases, encoder=JaxEncoder(),
+                                  large_block=LB, small_block=SB,
+                                  buffer_size=SB, batch_volumes=3)
+    by_op = _ec_spans(root)
+    assert sorted(by_op) == sorted(STAGES)
+
+    def total(op):
+        return sum(s["bytes"] for s in by_op[op])
+    dat = sum(os.path.getsize(b + ".dat") for b in bases)
+    shards = sum(os.path.getsize(b + pl.to_ext(i))
+                 for b in bases for i in range(14))
+    data_shards = sum(os.path.getsize(b + pl.to_ext(i))
+                      for b in bases for i in range(10))
+    assert total("pread") == dat
+    assert total("write") == shards
+    assert total("h2d") == total("launch") == data_shards
+    assert total("readback") == shards - data_shards
+    # 4 + 2 rows, launches of 3 rows: one of each device stage a launch,
+    # and h2d twice, for joining the launch's rows and for the transfer
+    assert len(by_op["pread"]) == 6
+    assert len(by_op["launch"]) == len(by_op["readback"]) == 2
+    assert len(by_op["h2d"]) == 4
+    # a write span per row, per launch's parity, per volume's closing
+    assert len(by_op["write"]) == 6 + 2 + 2
+    if metrics.HAVE_PROMETHEUS:
+        assert metrics.EC_ENCODE_BYTES._value.get() - before == data_shards
+
+
+def test_rebuild_stage_spans(tmp_path, fresh_tracing):
+    """rebuild_ec_files with a JaxEncoder: the reader, launcher and
+    writer threads' stage spans all parent to the request span; pread
+    bytes are the survivors read, write bytes the shards restored."""
+    from seaweedfs_tpu.ec.encoder_jax import JaxEncoder
+    (base,) = _dat_files(tmp_path, [5 * SB * 10 + 9])
+    pl.write_ec_files(base, encoder=pl.get_encoder("cpu"), large_block=LB,
+                      small_block=SB, buffer_size=SB)
+    want = {}
+    for sid in (0, 12):
+        with open(base + pl.to_ext(sid), "rb") as f:
+            want[sid] = f.read()
+        os.remove(base + pl.to_ext(sid))
+    fresh_tracing.reset()
+    with fresh_tracing.start_root("volume", "ec.rebuild") as root:
+        assert pl.rebuild_ec_files(base, encoder=JaxEncoder(),
+                                   buffer_size=SB,
+                                   batch_windows=2) == [0, 12]
+    for sid, data in want.items():
+        with open(base + pl.to_ext(sid), "rb") as f:
+            assert f.read() == data
+    by_op = _ec_spans(root)
+    assert sorted(by_op) == sorted(STAGES)
+    shard = len(want[0])
+    assert sum(s["bytes"] for s in by_op["pread"]) == 10 * shard
+    assert sum(s["bytes"] for s in by_op["write"]) == 2 * shard
+    # 6 windows in blocks of 2: three of every stage, and the outputs'
+    # closing is one more write
+    assert {op: len(v) for op, v in by_op.items()} == \
+        dict({op: 3 for op in STAGES}, write=4)
+
+
+def test_concurrent_degraded_reads_time_the_lock(ec_fixture, fresh_tracing):
+    """Two concurrent read_needle calls on one EcVolume: each records
+    `lock_wait` then `locked`; the call that comes second waits for the
+    first's hold. A recover's own stages nest under `ec`/`recover`."""
+    import threading
+
+    from seaweedfs_tpu.ec.encoder_jax import JaxEncoder
+    d, base, contents = ec_fixture
+    ev = EcVolume(d, "", 5, large_block=LB, small_block=SB,
+                  encoder=JaxEncoder())
+    ev.shards.pop(0).close()
+    inner = ev._read_shard_interval
+    held = threading.Event()
+
+    def slow_read(sid, off, size):
+        held.set()
+        time.sleep(0.05)
+        return inner(sid, off, size)
+    ev._read_shard_interval = slow_read
+    # past the needle test_ec_delete_journal tombstones in the fixture
+    nids = sorted(contents)[-2:]
+    got = {}
+
+    def get(nid):
+        with fresh_tracing.start_root("volume", "read") as root:
+            got[nid] = (ev.read_needle(nid), root)
+    first = threading.Thread(target=get, args=(nids[0],))
+    first.start()
+    assert held.wait(5)
+    second = threading.Thread(target=get, args=(nids[1],))
+    second.start()
+    first.join()
+    second.join()
+    ev._read_shard_interval = inner
+    waits = []
+    for nid in nids:
+        n, root = got[nid]
+        assert n.data == contents[nid]
+        by_op = _ec_spans(root)
+        assert len(by_op["lock_wait"]) == len(by_op["locked"]) == 1
+        waits.append(by_op["lock_wait"][0]["dur_ms"])
+    assert waits[1] > 0 and waits[1] > waits[0]
+    assert waits[1] >= 20
+    # a recover of shard 0 on the device path: its stages nest under it
+    needle = next(nid for nid in sorted(contents)[1:]
+                  if any(iv.to_shard_and_offset(LB, SB)[0] == 0
+                         for iv in locate_data(LB, SB, ev.dat_size,
+                                               *_record(ev, nid))))
+    fresh_tracing.reset()
+    with fresh_tracing.start_root("volume", "read") as root:
+        assert ev.read_needle(needle).data == contents[needle]
+    spans = fresh_tracing.trace_spans_dict(root.trace)["spans"]
+    rec = [s for s in spans if s["op"] == "recover"]
+    assert rec
+    under = {s["op"] for s in spans if s["parent"] == rec[0]["span"]}
+    assert {"pread", "h2d", "launch", "readback"} <= under
+    ev.close()
+
+
+def _record(ev, nid):
+    from seaweedfs_tpu.storage import types as t
+    off, size = ev.find_needle(nid)
+    return off, t.actual_size(size, ev.version)
+
+
+def test_profiler_trace_holds_stage_events(tmp_path, fresh_tracing):
+    """Under jax.profiler.start_trace on the CPU, the .xplane.pb host
+    plane holds the engine's stages as `<tier>.<op>` events."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+
+    from seaweedfs_tpu.ec.encoder_jax import JaxEncoder
+    bases = _dat_files(tmp_path, [2 * SB * 10 + 5])
+    enc = JaxEncoder()
+    pl.write_ec_files_batched(bases, encoder=enc, large_block=LB,
+                              small_block=SB, buffer_size=SB)
+    trace_dir = tmp_path / "trace"
+    jax.profiler.start_trace(str(trace_dir))
+    try:
+        with fresh_tracing.start_root("volume", "ec.generate_batch"):
+            pl.write_ec_files_batched(bases, encoder=enc, large_block=LB,
+                                      small_block=SB, buffer_size=SB)
+    finally:
+        jax.profiler.stop_trace()
+    (pb,) = glob.glob(str(trace_dir / "**" / "*.xplane.pb"), recursive=True)
+    names = {e.name for p in ProfileData.from_file(pb).planes
+             if p.name.startswith("/host:")
+             for line in p.lines for e in line.events}
+    assert {"ec." + op for op in STAGES} <= names
+    assert "volume.ec.generate_batch" in names

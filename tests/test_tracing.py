@@ -205,6 +205,195 @@ def test_executor_context_propagation_pattern():
     assert store["parent"] == root.span_id
 
 
+def test_run_in_executor_times_the_queue():
+    """Under a trace every executor hop records an `exec`/`queue` span
+    from the submit to the start in a worker thread: behind a busy
+    one-thread pool the second hop waits for the first to finish."""
+    from concurrent.futures import ThreadPoolExecutor
+
+    async def body():
+        loop = asyncio.get_running_loop()
+        loop.set_default_executor(ThreadPoolExecutor(1))
+        with tracing.start_root("volume", "read") as root:
+            def work():
+                with tracing.start("store", "read"):
+                    time.sleep(0.05)
+            await asyncio.gather(tracing.run_in_executor(work),
+                                 tracing.run_in_executor(work))
+        # untraced hops record nothing
+        await tracing.run_in_executor(time.sleep, 0)
+        return root
+
+    root = asyncio.run(body())
+    spans = tracing.traces_dict()["traces"][0]["spans"]
+    queued = [s for s in spans if (s["tier"], s["op"]) == ("exec", "queue")]
+    assert len(queued) == 2
+    assert all(s["parent"] == root.span_id for s in queued)
+    assert max(s["dur_ms"] for s in queued) >= 40
+    stores = [s for s in spans if s["tier"] == "store"]
+    assert [s["parent"] for s in stores] == [root.span_id] * 2
+    assert tracing.traces_dict()["spans"] == 5
+    assert tracing.requests_dict()["inflight"] == 0
+
+
+def test_overlapped_pipeline_threads_keep_the_parent():
+    """_run_overlapped's reader and writer threads run in copies of the
+    caller's context: spans opened there parent under the caller's."""
+    from seaweedfs_tpu.ec.pipeline import _run_overlapped
+
+    def read_batches():
+        for i in range(3):
+            with tracing.start("ec", "pread"):
+                pass
+            yield i
+
+    def launch(i):
+        with tracing.start("ec", "launch"):
+            return i, None
+
+    def write_result(i, _):
+        with tracing.start("ec", "write"):
+            pass
+
+    with tracing.start_root("volume", "ec.rebuild") as root:
+        _run_overlapped(read_batches(), launch, write_result)
+    spans = tracing.traces_dict()["traces"][0]["spans"]
+    ops = sorted(s["op"] for s in spans if s["tier"] == "ec")
+    assert ops == ["launch"] * 3 + ["pread"] * 3 + ["write"] * 3
+    assert all(s["parent"] == root.span_id
+               for s in spans if s["tier"] == "ec")
+
+
+def test_timed_lock_records_wait_and_hold_after_release():
+    """timed_lock times the wait and the hold as child spans that reach
+    the ring only once the lock is free again, on a raise too; untraced
+    it only takes the lock."""
+    import threading
+    lock = threading.Lock()
+    with tracing.timed_lock(lock, "ec"):
+        assert lock.locked()
+    assert tracing.traces_dict()["spans"] == 0
+    with tracing.start_root("volume", "read") as root:
+        lock.acquire()
+        threading.Timer(0.03, lock.release).start()
+        with pytest.raises(KeyError):
+            with tracing.timed_lock(lock, "ec"):
+                assert tracing.traces_dict()["spans"] == 0
+                time.sleep(0.02)
+                raise KeyError("gone")
+    assert not lock.locked()
+    spans = {s["op"]: s for s in tracing.traces_dict()["traces"][0]["spans"]}
+    assert spans["lock_wait"]["dur_ms"] >= 20
+    assert spans["locked"]["dur_ms"] >= 15
+    assert spans["lock_wait"]["parent"] == root.span_id
+    assert spans["locked"]["parent"] == root.span_id
+    wait, held = spans["lock_wait"], spans["locked"]
+    assert held["start_ms"] >= wait["start_ms"] + wait["dur_ms"] - 1
+
+
+def test_concurrent_spans_and_readers_lose_nothing():
+    """Many threads finishing spans while readers copy the ring, the
+    in-flight table and the exemplars: no span is lost and no reader
+    raises."""
+    import sys
+    import threading
+    n_threads, per_thread = 24, 40   # 1,920 spans: the ring holds 2,048
+    errors: list = []
+    done = threading.Event()
+
+    def write():
+        for _ in range(per_thread):
+            with tracing.start_root("volume", "read"):
+                with tracing.start("ec", "pread"):
+                    pass
+
+    def read():
+        try:
+            while not done.is_set():
+                tracing.traces_dict(recent=5, slowest=5)
+                tracing.requests_dict()
+                tracing.trace_spans_dict("0" * 32)
+                tracing.drain_exemplars()
+        except Exception as e:  # noqa: BLE001 — reported below
+            errors.append(e)
+
+    switch = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        readers = [threading.Thread(target=read) for _ in range(2)]
+        writers = [threading.Thread(target=write) for _ in range(n_threads)]
+        for t in readers + writers:
+            t.start()
+        for t in writers:
+            t.join(timeout=60)
+        done.set()
+        for t in readers:
+            t.join(timeout=60)
+    finally:
+        sys.setswitchinterval(switch)
+    assert not any(t.is_alive() for t in readers + writers)
+    assert errors == []
+    assert tracing.traces_dict(recent=0, slowest=0)["spans"] == \
+        2 * n_threads * per_thread
+    assert tracing.requests_dict()["inflight"] == 0
+
+
+def test_importing_tracing_does_not_import_jax():
+    """The client tools and the load generator import tracing and must
+    never pull in JAX: spans write profiler events only where the
+    process already imported it."""
+    code = ("import sys\n"
+            "from seaweedfs_tpu.util import tracing\n"
+            "with tracing.start_root('client', 'read'):\n"
+            "    with tracing.start('client', 'hop'):\n"
+            "        pass\n"
+            "assert tracing.traces_dict()['spans'] == 2\n"
+            "assert 'jax' not in sys.modules, 'jax imported'\n")
+    p = subprocess.run([sys.executable, "-c", code], cwd=REPO,
+                       capture_output=True, text=True, timeout=120)
+    assert p.returncode == 0, p.stderr
+
+
+def test_spans_are_profiler_host_events(tmp_path):
+    """While a JAX profiler session runs, a span is also a host event
+    `<tier>.<op>` covering the span, including one finished on another
+    thread than the one that opened it; outside a session, none."""
+    import glob
+    import threading
+
+    import jax
+    from jax.profiler import ProfileData
+
+    with tracing.start_root("volume", "early"):
+        pass
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with tracing.start_root("volume", "ec.rebuild"):
+            with tracing.start("ec", "pread"):
+                time.sleep(0.01)
+            handed = tracing.start("exec", "queue")
+            t = threading.Thread(target=lambda: (time.sleep(0.01),
+                                                 handed.finish()))
+            t.start()
+            t.join()
+    finally:
+        jax.profiler.stop_trace()
+    with tracing.start_root("volume", "late"):
+        pass
+    pb = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    assert len(pb) == 1
+    host = {}
+    for plane in ProfileData.from_file(pb[0]).planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                for e in line.events:
+                    host.setdefault(e.name, []).append(e.duration_ns)
+    assert len(host["ec.pread"]) == 1 and host["ec.pread"][0] >= 10e6
+    assert host["exec.queue"][0] >= 10e6
+    assert host["volume.ec.rebuild"][0] >= host["ec.pread"][0]
+    assert "volume.early" not in host and "volume.late" not in host
+
+
 def test_slow_request_glog_line(capsys):
     tracing.init(sample=1.0, slow_ms=1.0)
     with tracing.start_root("volume", "read"):
