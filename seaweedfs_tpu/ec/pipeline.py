@@ -42,6 +42,13 @@ from .locate import LARGE_BLOCK_SIZE, SMALL_BLOCK_SIZE
 # file writes (classic double buffering); more just holds memory
 _PIPE_DEPTH = 2
 
+# buffers one preadv call may scatter into (Linux's UIO_MAXIOV is 1024;
+# POSIX guarantees 16)
+try:
+    _IOV_MAX = max(16, os.sysconf("SC_IOV_MAX"))
+except (AttributeError, ValueError, OSError):
+    _IOV_MAX = 16
+
 
 def to_ext(shard_id: int) -> str:
     return ".ec%02d" % shard_id
@@ -556,16 +563,37 @@ def present_shards(base_name: str) -> list[int]:
             if os.path.exists(base_name + to_ext(i))]
 
 
+def _pread_into(fd: int, rows: list[np.ndarray], pos: int) -> int:
+    """Fill the byte rows `rows` in turn with the file's bytes from
+    `pos` on by scatter reads (`os.preadv`), looping on short reads
+    until the rows are full or the file ends; returns the bytes read."""
+    views = [memoryview(r) for r in rows]
+    got = i = 0
+    while i < len(views):
+        n = os.preadv(fd, views[i:i + _IOV_MAX], pos + got)
+        if not n:
+            break
+        got += n
+        while i < len(views) and n >= len(views[i]):
+            n -= len(views[i])
+            i += 1
+        if n:
+            views[i] = views[i][n:]
+    return got
+
+
 def _rebuild_rows(base_name: str, encoder, targets: list[int],
                   use: list[int], buffer_size: int,
                   stats: dict | None,
                   batch_windows: int = DEFAULT_BATCH_WINDOWS) -> None:
     """Regenerate the `targets` shard files from the k `use` shards
     through the stripe-batch engine: up to `batch_windows` buffer
-    windows gather into one (B, k, buf) block read with ONE pread per
-    survivor, and ONE encoder dispatch emits ALL target rows for every
-    window in the block (len(targets) x k coefficients) — ceil(W/B)
-    dispatches per rebuild instead of W."""
+    windows gather into one (B, k, buf) block, each survivor scattered
+    straight into its rows by ONE pread, and ONE encoder dispatch emits
+    ALL target rows for every window in the block (len(targets) x k
+    coefficients) — ceil(W/B) dispatches per rebuild instead of W.
+    Blocks are reused once their readback returns; `stats` counts
+    `blocks_allocated` and `blocks_reused`."""
     coeff = gf.cached_shard_rows(tuple(targets), tuple(use))
     shard_size = os.path.getsize(base_name + to_ext(use[0]))
     ins = [open(base_name + to_ext(i), "rb") for i in use]
@@ -574,36 +602,55 @@ def _rebuild_rows(base_name: str, encoder, targets: list[int],
     # resident budget: survivor rows in + target rows out per window
     batch_windows = clamp_batch_windows(batch_windows, buffer_size,
                                         len(use) + len(targets))
+    shape = (min(batch_windows, n_windows), len(use), buffer_size)
+    # blocks whose readback has returned, reused by the reader; the
+    # pipeline's queues bound how many are ever allocated
+    free: list[np.ndarray] = []
 
     def batches():
         for wi, count in window_blocks(n_windows, batch_windows):
             pos = wi * buffer_size
             take = min(count * buffer_size, shard_size - pos)
-            raws = []
+            try:
+                block = free.pop()
+                add_stat(stats, blocks_reused=1)
+            except IndexError:
+                block = np.empty(shape, np.uint8)
+                add_stat(stats, blocks_allocated=1)
+            rows = block[:count]
             with tracing.start("ec", "pread") as sp:
-                for f in ins:
-                    f.seek(pos)
-                    raws.append(f.read(take))
-                sp.nbytes = sum(map(len, raws))
-                # zero-pad the tail to whole windows: GF of zero rows is
-                # zero, and the pad is sliced off before writing
-                whole = count * buffer_size
-                block = np.stack(
-                    [np.frombuffer(raw + b"\x00" * (whole - len(raw)),
-                                   np.uint8).reshape(count, buffer_size)
-                     for raw in raws], axis=1)
+                got = 0
+                for i, f in enumerate(ins):
+                    n = _pread_into(f.fileno(), [
+                        rows[w, i, :take - w * buffer_size]
+                        for w in range(-(-take // buffer_size))], pos)
+                    got += n
+                    # zero what was not read (the tail's pad, a short
+                    # survivor): a reused block still holds an earlier
+                    # block's bytes there. GF of zero columns is zero,
+                    # and the pad is sliced off before writing
+                    w, r = divmod(n, buffer_size)
+                    if w < count:
+                        rows[w, i, r:] = 0
+                        rows[w + 1:, i] = 0
+                sp.nbytes = got
             add_stat(stats, preads=len(ins), bytes_read=take * len(ins))
-            yield block, take
+            yield block, count, take
 
     def launch(item):
-        block, take = item
+        block, count, _ = item
         if stats is not None:
             stats["launches"] = stats.get("launches", 0) + 1
-        return item, transform_block_async(encoder, coeff, block, stats)
+        return item, transform_block_async(encoder, coeff, block[:count],
+                                           stats)
 
     def write_result(item, thunk):
-        block, take = item
+        block, _, take = item
         rebuilt = thunk()       # (B, targets, buf)
+        # the result is back, so the device transfer that read the block
+        # is done: only now may the reader overwrite it. A stage that
+        # fails drops its blocks instead
+        free.append(block)
         with tracing.start("ec", "write") as sp:
             for j, o in enumerate(outs):
                 out = np.ascontiguousarray(rebuilt[:, j, :]
@@ -641,7 +688,8 @@ def rebuild_ec_files(base_name: str, encoder=None,
     PER lost shard) as the baseline tools/bench_ec.py measures the
     batching win against; `stats` (optional dict) accumulates
     bytes_read / bytes_rebuilt / launches / dispatches / preads /
-    windows / seconds for that repair-bandwidth accounting.
+    windows / seconds for that repair-bandwidth accounting, and
+    blocks_allocated / blocks_reused for the reader's block reuse.
 
     `targets` restricts WHICH absent shards are regenerated (the
     rebuild-to-target admin route: a node rebuilding one shard it will

@@ -504,6 +504,128 @@ def test_rebuild_stage_spans(tmp_path, fresh_tracing):
         dict({op: 3 for op in STAGES}, write=4)
 
 
+def _encoder(name):
+    if name == "jax":
+        from seaweedfs_tpu.ec.encoder_jax import JaxEncoder
+        return JaxEncoder()
+    return pl.get_encoder("cpu")
+
+
+def _striped(tmp_path, lost, dat_size=40 * SB * 10 - 5):
+    """A .dat striped to 14 shard files; the `lost` shards' bytes are
+    returned and their files removed."""
+    (base,) = _dat_files(tmp_path, [dat_size])
+    pl.write_ec_files(base, encoder=pl.get_encoder("cpu"), large_block=LB,
+                      small_block=SB, buffer_size=SB)
+    want = {}
+    for sid in lost:
+        with open(base + pl.to_ext(sid), "rb") as f:
+            want[sid] = f.read()
+        os.remove(base + pl.to_ext(sid))
+    return base, want
+
+
+@pytest.mark.parametrize("buffer_size,batch_windows,sequential", [
+    (2000, 2, False),   # a ragged last window in a one-window tail block
+    (SB, 4, False),     # whole windows, ten blocks
+    (SB, 1, True),      # the sequential baseline's (1, 10, L) blocks
+], ids=["ragged_tail", "whole_windows", "sequential"])
+@pytest.mark.parametrize("enc", ["cpu", "jax"])
+def test_rebuild_reuses_blocks(tmp_path, enc, buffer_size, batch_windows,
+                               sequential):
+    """The rebuild reader scatters survivors into reused blocks: the
+    restored shards are byte-identical, every block is either new or
+    reused, and no call allocates more than its pipeline can hold."""
+    lost = (0, 12)
+    base, want = _striped(tmp_path, lost)
+    encoder = _encoder(enc)
+    stats: dict = {}
+    assert pl.rebuild_ec_files(base, encoder=encoder,
+                               buffer_size=buffer_size,
+                               batch_windows=batch_windows,
+                               sequential=sequential,
+                               stats=stats) == list(lost)
+    for sid, data in want.items():
+        with open(base + pl.to_ext(sid), "rb") as f:
+            assert f.read() == data, sid
+    shard = len(want[0])
+    windows = -(-shard // buffer_size)
+    calls = len(lost) if sequential else 1
+    blocks = -(-windows // batch_windows) * calls
+    assert blocks >= 8 * calls
+    assert stats["blocks_allocated"] + stats["blocks_reused"] == blocks
+    assert stats["blocks_reused"] > 0
+    # reader, two queued, launcher, two queued, writer
+    bound = 2 * pl._PIPE_DEPTH + 3 if pl._use_overlap(encoder) else 1
+    assert stats["blocks_allocated"] <= bound * calls
+
+
+@pytest.mark.parametrize("enc", ["cpu", "jax"])
+def test_rebuild_short_survivor_reads_zeros(tmp_path, enc):
+    """A survivor cut short by less than a window reads as zeros past
+    its end, as if padded, though earlier blocks filled the reused
+    block's memory with survivor bytes."""
+    from seaweedfs_tpu.ec.encoder_cpu import CpuEncoder
+    base, want = _striped(tmp_path, (0,))
+    shard = len(want[0])
+    short = base + pl.to_ext(7)
+    os.truncate(short, shard - SB // 3)
+    use = list(range(1, 11))
+    survivors = []
+    for sid in use:
+        with open(base + pl.to_ext(sid), "rb") as f:
+            raw = f.read()
+        survivors.append(np.frombuffer(raw + bytes(shard - len(raw)),
+                                       np.uint8))
+    (expect,) = pl._transform_buffers(
+        CpuEncoder(use_native=False), gf.cached_shard_rows((0,), tuple(use)),
+        survivors)
+    stats: dict = {}
+    assert pl.rebuild_ec_files(base, encoder=_encoder(enc), buffer_size=SB,
+                               batch_windows=4, stats=stats) == [0]
+    assert stats["blocks_reused"] > 0
+    with open(base + pl.to_ext(0), "rb") as f:
+        got = f.read()
+    assert got == np.asarray(expect, np.uint8).tobytes()
+    # the cut bytes were not zeros, so the restored shard is not the
+    # original there: the zeros did stand in for them
+    assert got[:shard - SB] == want[0][:shard - SB]
+    assert got != want[0]
+
+
+def test_rebuild_block_outlives_its_readback(tmp_path, monkeypatch):
+    """A block goes back for reuse only once its readback has returned:
+    every launched block still holds the bytes it was launched with
+    when its thunk finishes."""
+    import hashlib
+    lost = (3,)
+    base, want = _striped(tmp_path, lost)
+    launched = pl.transform_block_async
+    seen = []
+
+    def recording(encoder, coeff, block, stats=None):
+        before = hashlib.sha1(block.tobytes()).hexdigest()
+        thunk = launched(encoder, coeff, block, stats)
+
+        def readback():
+            # leave the reader time to overwrite a block released early
+            time.sleep(0.005)
+            out = thunk()
+            seen.append((before, hashlib.sha1(block.tobytes()).hexdigest()))
+            return out
+        return readback
+    monkeypatch.setattr(pl, "transform_block_async", recording)
+    stats: dict = {}
+    assert pl.rebuild_ec_files(base, encoder=_encoder("jax"),
+                               buffer_size=SB, batch_windows=1,
+                               stats=stats) == list(lost)
+    assert stats["blocks_reused"] > 0
+    assert len(seen) == stats["launches"]
+    assert all(a == b for a, b in seen)
+    with open(base + pl.to_ext(3), "rb") as f:
+        assert f.read() == want[3]
+
+
 def test_concurrent_degraded_reads_time_the_lock(ec_fixture, fresh_tracing):
     """Two concurrent read_needle calls on one EcVolume: each records
     `lock_wait` then `locked`; the call that comes second waits for the
