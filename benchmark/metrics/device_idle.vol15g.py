@@ -1,0 +1,3 @@
+"""Share of the traced window in which no op runs on the device."""
+
+from trace_reduce import idle_share as read  # noqa: F401

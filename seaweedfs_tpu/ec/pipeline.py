@@ -35,7 +35,7 @@ from ..util import tracing
 from . import gf
 from .batch import (DEFAULT_BATCH_WINDOWS, add_stat, clamp_batch_windows,
                     transform_block_async, window_blocks)
-from .locate import LARGE_BLOCK_SIZE, SMALL_BLOCK_SIZE
+from .locate import LARGE_BLOCK_SIZE, SMALL_BLOCK_SIZE, n_large_block_rows
 
 # read-ahead / dispatch-ahead depth of the threaded encode pipeline: 2 is
 # enough to overlap file reads, host<->device transfer + kernel time, and
@@ -198,6 +198,63 @@ def _iter_row_batches(dat_size: int, large_block: int, small_block: int,
         remaining -= small_block * gf.DATA_SHARDS
 
 
+class _RowSpans:
+    """The `ec`/`rows.large` and `ec`/`rows.small` spans of one volume's
+    encode: one per run of consecutive rows of one block size (a volume
+    has at most a large run, then a small one). A run's span opens at
+    its first pread and finishes once the last of its
+    `_iter_row_batches` specs is enqueued on the encoder, with `bytes`
+    the .dat bytes the run covers; those bytes then count in
+    SeaweedFS_ec_stripe_bytes_total{block}. Spans of a failed encode
+    finish as errors in `close` and count nothing."""
+
+    def __init__(self, dat_size: int, large_block: int, small_block: int,
+                 buffer_size: int):
+        n_large = n_large_block_rows(large_block, dat_size)
+        large = n_large * large_block * gf.DATA_SHARDS
+        small_rows = -(-(dat_size - large) // (small_block * gf.DATA_SHARDS))
+        # [block kind, specs not yet enqueued, .dat bytes]
+        self._runs = [
+            ["large", n_large * (large_block
+                                 // min(buffer_size, large_block)), large],
+            ["small", small_rows * (small_block
+                                    // min(buffer_size, small_block)),
+             dat_size - large]]
+        self._split = self._runs[0][1]   # specs of the large run
+        self._open: dict[int, object] = {}
+
+    def reading(self, spec: int) -> None:
+        """Spec `spec` (its index in `_iter_row_batches`) is about to be
+        read: open its run's span if this is the run's first read."""
+        r = int(spec >= self._split)
+        if r not in self._open and self._runs[r][1] > 0:
+            self._open[r] = tracing.start("ec", "rows." + self._runs[r][0])
+
+    def enqueued(self, spec: int) -> None:
+        """Spec `spec`'s group is enqueued: the run ends with its last."""
+        r = int(spec >= self._split)
+        run = self._runs[r]
+        run[1] -= 1
+        if run[1] == 0:
+            self._open.pop(r).finish(nbytes=run[2])
+            _count_striped(run[0], run[2])
+
+    def close(self) -> None:
+        while self._open:
+            self._open.popitem()[1].finish(status="error")
+
+
+def _count_striped(block: str, nbytes: int) -> None:
+    """SeaweedFS_ec_stripe_bytes_total{block}: .dat bytes striped in
+    `block` ("large" or "small") stripe rows, by both encode engines."""
+    try:
+        from ..stats import metrics
+    except ImportError:
+        return
+    if metrics.HAVE_PROMETHEUS:
+        metrics.EC_STRIPE_BYTES.labels(block=block).inc(int(nbytes))
+
+
 def _close_outputs(files) -> None:
     """Close written shard files inside an `ec`/`write` span: a close
     can block on write-back of what the writes left in the page cache
@@ -344,23 +401,27 @@ def encode_volume(base_name: str, encoder=None,
     dat_size = os.path.getsize(dat_path)
     outs = [open(base_name + to_ext(i), "wb") for i in range(gf.TOTAL_SHARDS)]
     f = open(dat_path, "rb")
+    rows = _RowSpans(dat_size, large_block, small_block, buffer_size)
 
     def groups():
+        """(index of the group's first spec, the group's specs)."""
         pending: list[tuple] = []
+        first = 0
         limit = 1
-        for spec in _iter_row_batches(dat_size, large_block, small_block,
-                                      buffer_size):
+        for i, spec in enumerate(_iter_row_batches(
+                dat_size, large_block, small_block, buffer_size)):
             if pending and (spec[2] != pending[0][2]
                             or len(pending) >= limit):
-                yield pending
+                yield first, pending
                 pending = []
             if not pending:
+                first = i
                 # resident budget: data rows + parity rows per window
                 limit = clamp_batch_windows(batch_windows, spec[2],
                                             gf.TOTAL_SHARDS)
             pending.append(spec)
         if pending:
-            yield pending
+            yield first, pending
 
     def read_block(group):
         """One (B, 10, buf) block read straight into its final array;
@@ -394,17 +455,23 @@ def encode_volume(base_name: str, encoder=None,
         return block, preads
 
     def batches():
-        for group in groups():
-            yield read_block(group)
+        for first, group in groups():
+            specs = range(first, first + len(group))
+            for i in specs:
+                rows.reading(i)
+            yield (*read_block(group), specs)
 
     def launch(item):
-        block, preads = item
+        block, preads, specs = item
         add_stat(stats, preads=preads, bytes_read=int(block.nbytes))
         _count_encoded(block.nbytes)
-        return item, transform_block_async(encoder, parity, block, stats)
+        thunk = transform_block_async(encoder, parity, block, stats)
+        for i in specs:
+            rows.enqueued(i)
+        return item, thunk
 
     def write_result(item, thunk):
-        block, _ = item
+        block = item[0]
         parities = thunk()      # (B, m, buf)
         with tracing.start("ec", "write") as sp:
             for i in range(gf.DATA_SHARDS):
@@ -418,6 +485,7 @@ def encode_volume(base_name: str, encoder=None,
         _run_overlapped(batches(), launch, write_result,
                         overlap=_use_overlap(encoder))
     finally:
+        rows.close()
         f.close()
         _close_outputs(outs)
 
@@ -452,15 +520,19 @@ def write_ec_files_batched(base_names: list[str], encoder=None,
     same batch shards over devices.
 
     Parity buffers surface in flush order, not stream order, so every
-    parity write lands at an explicitly recorded shard offset.
+    parity write lands at an explicitly recorded shard offset. In a
+    large row the ten preads of a buffer lie a large block apart in the
+    .dat; in a small row they are contiguous.
     """
     import collections
 
     encoder = encoder or get_encoder()
     parity = gf.parity_matrix()
     outs: dict[str, list] = {}
-    # buf_len -> list of (data_buffers, base, parity_shard_offset)
+    # buf_len -> list of (data_buffers, base, parity_shard_offset,
+    # the volume's _RowSpans, the buffer's spec index)
     pending: dict[int, list] = {}
+    row_spans: list[_RowSpans] = []
     pending_refs: dict[str, int] = {}   # base -> unflushed group count
     fully_enqueued: set[str] = set()
     # launched-but-unwritten kernel batches: lets the next group's file
@@ -479,7 +551,7 @@ def write_ec_files_batched(base_names: list[str], encoder=None,
         parities = thunk()
         with tracing.start("ec", "write") as sp:
             off = 0
-            for buffers, base, shard_off in group:
+            for buffers, base, shard_off, *_ in group:
                 ln = len(buffers[0])
                 for p, pbuf in enumerate(parities):
                     f = outs[base][gf.DATA_SHARDS + p]
@@ -488,7 +560,7 @@ def write_ec_files_batched(base_names: list[str], encoder=None,
                                        np.uint8).tobytes())
                 off += ln
             sp.nbytes = off * len(parities)
-        for _, base, _ in group:
+        for _, base, *_ in group:
             pending_refs[base] -= 1
             maybe_close(base)
 
@@ -505,6 +577,8 @@ def write_ec_files_batched(base_names: list[str], encoder=None,
         _count_encoded(len(cat[0]) * gf.DATA_SHARDS)
         inflight.append(
             (group, _transform_buffers_async(encoder, parity, cat)))
+        for _, _, _, runs, spec in group:
+            runs.enqueued(spec)
         while len(inflight) > _PIPE_DEPTH:
             drain_one()
 
@@ -515,9 +589,14 @@ def write_ec_files_batched(base_names: list[str], encoder=None,
             outs[base] = [open(base + to_ext(i), "wb")
                           for i in range(gf.TOTAL_SHARDS)]
             shard_pos = 0
+            rows = _RowSpans(dat_size, large_block, small_block,
+                             buffer_size)
+            row_spans.append(rows)
             with open(dat_path, "rb") as f:
-                for start, block_size, buf, b in _iter_row_batches(
-                        dat_size, large_block, small_block, buffer_size):
+                for spec, (start, block_size, buf, b) in enumerate(
+                        _iter_row_batches(dat_size, large_block,
+                                          small_block, buffer_size)):
+                    rows.reading(spec)
                     raws = []
                     with tracing.start("ec", "pread") as sp:
                         for i in range(gf.DATA_SHARDS):
@@ -531,7 +610,7 @@ def write_ec_files_batched(base_names: list[str], encoder=None,
                         sp.nbytes = buf * gf.DATA_SHARDS
                     buffers = [np.frombuffer(raw, np.uint8) for raw in raws]
                     pending.setdefault(buf, []).append(
-                        (buffers, base, shard_pos))
+                        (buffers, base, shard_pos, rows, spec))
                     pending_refs[base] = pending_refs.get(base, 0) + 1
                     shard_pos += buf
                     if len(pending[buf]) >= batch_volumes:
@@ -543,6 +622,8 @@ def write_ec_files_batched(base_names: list[str], encoder=None,
         while inflight:
             drain_one()
     finally:
+        for runs in row_spans:
+            runs.close()
         for fs in outs.values():
             for f in fs:
                 f.close()

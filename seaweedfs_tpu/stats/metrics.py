@@ -75,6 +75,12 @@ if HAVE_PROMETHEUS:
     EC_ENCODE_BYTES = Counter(
         "SeaweedFS_ec_encode_bytes_total", "bytes erasure-encoded",
         registry=REGISTRY)
+    # .dat bytes striped, by the stripe block size of their rows
+    # (ec/pipeline.py: 1 GB "large" rows, then 1 MB "small" ones)
+    EC_STRIPE_BYTES = Counter(
+        "SeaweedFS_ec_stripe_bytes_total",
+        ".dat bytes striped into EC shards, by stripe block kind",
+        ["block"], registry=REGISTRY)
     # tiered read caches (util/chunk_cache.py): one label per cache —
     # "needle" (volume hot needles), "chunk" (filer/s3/webdav whole
     # chunks), "ec_recover" (degraded-read reconstructions),

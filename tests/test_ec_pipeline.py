@@ -448,7 +448,8 @@ def test_batched_encode_stage_spans(tmp_path, fresh_tracing):
                                   large_block=LB, small_block=SB,
                                   buffer_size=SB, batch_volumes=3)
     by_op = _ec_spans(root)
-    assert sorted(by_op) == sorted(STAGES)
+    # no large rows: each volume's one small run
+    assert sorted(by_op) == sorted(STAGES + ("rows.small",))
 
     def total(op):
         return sum(s["bytes"] for s in by_op[op])
@@ -470,6 +471,105 @@ def test_batched_encode_stage_spans(tmp_path, fresh_tracing):
     assert len(by_op["write"]) == 6 + 2 + 2
     if metrics.HAVE_PROMETHEUS:
         assert metrics.EC_ENCODE_BYTES._value.get() - before == data_shards
+    assert sorted(s["bytes"] for s in by_op["rows.small"]) == sorted(
+        os.path.getsize(b + ".dat") for b in bases)
+
+
+# the served route at a geometry with one large row and a small tail in
+# the row mix of a full 30,000 MB volume: 68% of the bytes in large rows
+LB_MIX = 64 * 1024
+SB_MIX = 4 * 1024
+DAT_MIX = 10 * LB_MIX + 300 * 1024
+
+
+def _oracle_shards(dat: bytes, large: int, small: int):
+    """RS(10,4) shards of `dat` in SeaweedFS's stripe layout with the CPU
+    encoder, and the .dat bytes in large and in small rows."""
+    from seaweedfs_tpu.ec.encoder_cpu import CpuEncoder
+    enc = CpuEncoder()
+    shards = [bytearray() for _ in range(14)]
+    pos = 0
+    split = {"large": 0, "small": 0}
+    for block in (large, small):
+        kind = "large" if block == large else "small"
+        while (len(dat) - pos > 10 * large if block == large
+               else pos < len(dat)):
+            row = dat[pos:pos + 10 * block].ljust(10 * block, b"\0")
+            data = [np.frombuffer(row[i * block:(i + 1) * block], np.uint8)
+                    for i in range(10)]
+            for sid, out in enumerate(enc.encode(data)):
+                shards[sid] += np.asarray(out, np.uint8).tobytes()
+            split[kind] += min(10 * block, len(dat) - pos)
+            pos += 10 * block
+    return [bytes(s) for s in shards], split
+
+
+def _sorted_idx(idx: bytes) -> bytes:
+    """The .ecx of an .idx: the last entry per key, by ascending key."""
+    last = {}
+    for i in range(0, len(idx), 16):
+        last[idx[i:i + 8]] = idx[i:i + 16]
+    return b"".join(last[k] for k in sorted(last))
+
+
+@pytest.mark.parametrize("enc", ["cpu", "jax"])
+def test_generate_batch_route_large_and_small_rows(tmp_path, fresh_tracing,
+                                                   monkeypatch, enc):
+    """/admin/ec/generate_batch on a volume of one 10 x 64 KB large row
+    and a 300 KB tail of 4 KB small rows: every shard and the .ecx are
+    byte-exact, the stripe-bytes counter splits the .dat as the stripe
+    layout does, and each row kind is one span with those bytes."""
+    import asyncio
+
+    from seaweedfs_tpu.stats import metrics
+    from tests.cluster_util import Cluster
+
+    if enc == "jax":
+        monkeypatch.setattr(pl, "get_encoder",
+                            lambda backend="auto": _encoder("jax"))
+    striped = {k: (metrics.EC_STRIPE_BYTES.labels(block=k)._value.get()
+                   if metrics.HAVE_PROMETHEUS else 0)
+               for k in ("large", "small")}
+
+    async def main():
+        async with Cluster(str(tmp_path), n_servers=1,
+                           ec_large_block=LB_MIX,
+                           ec_small_block=SB_MIX) as c:
+            vs = c.servers[0]
+            vol = vs.store.add_volume(7)
+            rng = np.random.default_rng(5)
+            nid = 0
+            while vol.data_size() < DAT_MIX:
+                nid += 1
+                vol.write_needle(Needle(cookie=nid, id=nid, data=rng.integers(
+                    0, 256, int(rng.integers(1, 8192)),
+                    dtype=np.uint8).tobytes()))
+            fresh_tracing.reset()
+            async with c.http.post(
+                    f"http://{vs.url}/admin/ec/generate_batch",
+                    params={"volumes": "7"}) as resp:
+                assert resp.status == 200, await resp.text()
+            return vol.file_name()
+    base = asyncio.run(main())
+    with open(base + ".dat", "rb") as f:
+        dat = f.read()
+    assert len(dat) > 10 * LB_MIX
+    want, split = _oracle_shards(dat, LB_MIX, SB_MIX)
+    assert split == {"large": 10 * LB_MIX, "small": len(dat) - 10 * LB_MIX}
+    for sid in range(14):
+        with open(base + pl.to_ext(sid), "rb") as f:
+            assert f.read() == want[sid], sid
+    with open(base + ".idx", "rb") as f, open(base + ".ecx", "rb") as g:
+        assert g.read() == _sorted_idx(f.read())
+    if metrics.HAVE_PROMETHEUS:
+        for k in ("large", "small"):
+            got = metrics.EC_STRIPE_BYTES.labels(block=k)._value.get()
+            assert got - striped[k] == split[k], k
+    with fresh_tracing._lock:
+        rows = [(s.op, s.nbytes) for s in fresh_tracing._ring
+                if s.tier == "ec" and s.op.startswith("rows.")]
+    assert sorted(rows) == [("rows.large", split["large"]),
+                            ("rows.small", split["small"])]
 
 
 def test_rebuild_stage_spans(tmp_path, fresh_tracing):

@@ -67,7 +67,8 @@ REBUILD4 = gf.shard_rows([0, 1, 2, 3], list(range(4, gf.TOTAL_SHARDS)))
 @pytest.mark.parametrize("shard_mb", [8, 64])
 def test_words_transform_compiles(one_chip, coeff, shard_mb):
     # 8 MB/shard: the stripe-batch block (8 windows x 1 MB) ec.encode
-    # dispatches; 64 MB/shard: chip_smoke's kernel check
+    # dispatches for small rows; 64 MB/shard: its large-row flush (8
+    # buffers of 8 MB joined per shard) and chip_smoke's kernel check
     consts = gf.bitplane_constants(coeff)
     x = jax.ShapeDtypeStruct((shard_mb * MB // 512, 128), jnp.uint32,
                              sharding=one_chip)
