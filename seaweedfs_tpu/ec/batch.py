@@ -5,13 +5,13 @@ Cauchy-matrix multiply over device-resident stripe batches) is only as
 fast as its *scheduling*: window-at-a-time dispatch is latency-bound
 long before the field math matters (PAPERS.md 2108.02692 and 1312.5155
 draw the same conclusion at CPU scale). This module is the shared
-execution engine for the three bulk EC paths — whole-volume encode
-(`pipeline.encode_volume`), parity scrub (`EcVolume.verify_parity` /
-`ec/scrub.py`) and whole-volume rebuild (`pipeline.rebuild_ec_files`):
+execution engine for the bulk EC paths — whole-volume encode
+(`pipeline.encode_volume`) and parity scrub (`EcVolume.verify_parity` /
+`ec/scrub.py`):
 
 * gather **B stripe windows into one `(B, k, L)` uint8 block**;
-* run encode / verify / reconstruct as **ONE batched transform per
-  block** (`encoder.transform_batch`: the CPU backends flatten the
+* run encode / verify as **ONE batched transform per block**
+  (`encoder.transform_batch`: the CPU backends flatten the
   batch into the byte axis — the GF transform is columnwise, so the
   batch dim is free — while `JaxEncoder` jits a vmapped bitplane
   transform once per `(rows, k)` shape and shards the block along the
@@ -20,6 +20,13 @@ execution engine for the three bulk EC paths — whole-volume encode
 * account every dispatch, pread and byte **deterministically** (the
   `stats` dicts below), so tools/bench_ec.py can gate the batching win
   on arithmetic instead of wall clock.
+
+Whole-volume rebuild (`pipeline.rebuild_ec_files`) batches its windows
+the same way — `window_blocks` and `clamp_batch_windows` size its blocks
+and it keeps the same `stats` — but gathers them shard-major, one
+contiguous row per survivor, and launches the rows through the
+pipeline's per-row `_transform_buffers_async`, so the block reaches the
+kernel as free u32 word views with no device-side layout work.
 
 The GF(256) transform is independent per byte column, so batching is
 *exact*: a `(B, k, L)` block transforms to the same bytes as B separate

@@ -50,6 +50,12 @@ except (AttributeError, ValueError, OSError):
     _IOV_MAX = 16
 
 
+# the GF words kernel's block quantum in bytes (ops/gf256_pallas.py:
+# _DEFAULT_BM rows of 128 u32 words): a byte row of a multiple of it
+# views as (wm, 128) u32 words with no copy (`bytes_to_words`)
+_WORD_QUANTUM = 256 * 512
+
+
 def to_ext(shard_id: int) -> str:
     return ".ec%02d" % shard_id
 
@@ -663,17 +669,30 @@ def _pread_into(fd: int, rows: list[np.ndarray], pos: int) -> int:
     return got
 
 
+def _words_len(n: int) -> int:
+    """`n` bytes rounded up to the words kernel's block quantum."""
+    return -(-n // _WORD_QUANTUM) * _WORD_QUANTUM
+
+
 def _rebuild_rows(base_name: str, encoder, targets: list[int],
                   use: list[int], buffer_size: int,
                   stats: dict | None,
                   batch_windows: int = DEFAULT_BATCH_WINDOWS) -> None:
     """Regenerate the `targets` shard files from the k `use` shards
     through the stripe-batch engine: up to `batch_windows` buffer
-    windows gather into one (B, k, buf) block, each survivor scattered
-    straight into its rows by ONE pread, and ONE encoder dispatch emits
-    ALL target rows for every window in the block (len(targets) x k
-    coefficients) — ceil(W/B) dispatches per rebuild instead of W.
-    Blocks are reused once their readback returns; `stats` counts
+    windows gather into one shard-major (k, R) block, each survivor's
+    bytes for the block read by ONE pread into one contiguous row, and
+    ONE launch emits ALL target rows for every window in the block
+    (len(targets) x k coefficients) — ceil(W/B) dispatches per rebuild
+    instead of W.
+
+    The launch is encode's `_transform_buffers_async` on the block's k
+    rows, cut to a multiple of the words kernel's block quantum
+    (`_WORD_QUANTUM`, to which R is rounded up): each row reaches the
+    device as a free (wm, 128) u32 view, so the kernel runs with no
+    device-side transpose, reshape or stack around it, and each target's
+    readback row is written to its file as it comes back. Blocks are
+    reused once their readback returns; `stats` counts
     `blocks_allocated` and `blocks_reused`."""
     coeff = gf.cached_shard_rows(tuple(targets), tuple(use))
     shard_size = os.path.getsize(base_name + to_ext(use[0]))
@@ -683,7 +702,8 @@ def _rebuild_rows(base_name: str, encoder, targets: list[int],
     # resident budget: survivor rows in + target rows out per window
     batch_windows = clamp_batch_windows(batch_windows, buffer_size,
                                         len(use) + len(targets))
-    shape = (min(batch_windows, n_windows), len(use), buffer_size)
+    shape = (len(use), _words_len(min(batch_windows, n_windows)
+                                  * buffer_size))
     # blocks whose readback has returned, reused by the reader; the
     # pipeline's queues bound how many are ever allocated
     free: list[np.ndarray] = []
@@ -692,52 +712,45 @@ def _rebuild_rows(base_name: str, encoder, targets: list[int],
         for wi, count in window_blocks(n_windows, batch_windows):
             pos = wi * buffer_size
             take = min(count * buffer_size, shard_size - pos)
+            n = _words_len(take)
             try:
                 block = free.pop()
                 add_stat(stats, blocks_reused=1)
             except IndexError:
                 block = np.empty(shape, np.uint8)
                 add_stat(stats, blocks_allocated=1)
-            rows = block[:count]
             with tracing.start("ec", "pread") as sp:
                 got = 0
                 for i, f in enumerate(ins):
-                    n = _pread_into(f.fileno(), [
-                        rows[w, i, :take - w * buffer_size]
-                        for w in range(-(-take // buffer_size))], pos)
-                    got += n
+                    r = _pread_into(f.fileno(), [block[i, :take]], pos)
+                    got += r
                     # zero what was not read (the tail's pad, a short
                     # survivor): a reused block still holds an earlier
                     # block's bytes there. GF of zero columns is zero,
                     # and the pad is sliced off before writing
-                    w, r = divmod(n, buffer_size)
-                    if w < count:
-                        rows[w, i, r:] = 0
-                        rows[w + 1:, i] = 0
+                    block[i, r:n] = 0
                 sp.nbytes = got
             add_stat(stats, preads=len(ins), bytes_read=take * len(ins))
             yield block, count, take
 
     def launch(item):
-        block, count, _ = item
-        if stats is not None:
-            stats["launches"] = stats.get("launches", 0) + 1
-        return item, transform_block_async(encoder, coeff, block[:count],
-                                           stats)
+        block, count, take = item
+        add_stat(stats, dispatches=1, batches=1, windows=count,
+                 bytes_in=count * buffer_size * len(use), launches=1)
+        rows = list(block[:, :_words_len(take)])
+        return item, _transform_buffers_async(encoder, coeff, rows)
 
     def write_result(item, thunk):
         block, _, take = item
-        rebuilt = thunk()       # (B, targets, buf)
+        rebuilt = thunk()       # one row per target
         # the result is back, so the device transfer that read the block
         # is done: only now may the reader overwrite it. A stage that
         # fails drops its blocks instead
         free.append(block)
         with tracing.start("ec", "write") as sp:
-            for j, o in enumerate(outs):
-                out = np.ascontiguousarray(rebuilt[:, j, :]
-                                           ).tobytes()[:take]
-                add_stat(stats, bytes_rebuilt=len(out))
-                o.write(out)
+            for row, o in zip(rebuilt, outs):
+                o.write(row[:take])
+            add_stat(stats, bytes_rebuilt=take * len(outs))
             sp.nbytes = take * len(outs)
 
     try:

@@ -625,20 +625,41 @@ def _striped(tmp_path, lost, dat_size=40 * SB * 10 - 5):
     return base, want
 
 
-@pytest.mark.parametrize("buffer_size,batch_windows,sequential", [
-    (2000, 2, False),   # a ragged last window in a one-window tail block
-    (SB, 4, False),     # whole windows, ten blocks
-    (SB, 1, True),      # the sequential baseline's (1, 10, L) blocks
-], ids=["ragged_tail", "whole_windows", "sequential"])
+@pytest.mark.parametrize("buffer_size,batch_windows,sequential,lost", [
+    (2000, 2, False, (0, 12)),  # a ragged last window in a one-window tail
+    (SB, 4, False, (0, 12)),    # whole windows, ten blocks
+    (SB, 1, True, (0, 12)),     # the sequential baseline's one-window blocks
+    (2000, 2, False, (0,)),
+    (2000, 2, False, (1, 4, 10, 13)),
+], ids=["ragged_tail", "whole_windows", "sequential", "lost1_ragged_tail",
+        "lost4_ragged_tail"])
 @pytest.mark.parametrize("enc", ["cpu", "jax"])
-def test_rebuild_reuses_blocks(tmp_path, enc, buffer_size, batch_windows,
-                               sequential):
-    """The rebuild reader scatters survivors into reused blocks: the
-    restored shards are byte-identical, every block is either new or
-    reused, and no call allocates more than its pipeline can hold."""
-    lost = (0, 12)
+def test_rebuild_reuses_blocks(tmp_path, monkeypatch, enc, buffer_size,
+                               batch_windows, sequential, lost):
+    """The rebuild reader reads survivors into reused shard-major blocks
+    and launches each block's rows as one `_transform_buffers_async`
+    call: the restored shards are byte-identical to what the CPU oracle
+    striped, the rows are views of the block that view as kernel words
+    with no copy, every block is either new or reused, and no call
+    allocates more than its pipeline can hold."""
+    from seaweedfs_tpu.ops import gf256_pallas as gp
+    assert pl._WORD_QUANTUM == gp._DEFAULT_BM * gp._BLOCK_BYTES
     base, want = _striped(tmp_path, lost)
     encoder = _encoder(enc)
+    launched = pl._transform_buffers_async
+    blocks_seen: list = []
+
+    def recording(encoder, coeff, rows):
+        block = rows[0].base
+        assert block is not None and block.shape[0] == len(rows) == \
+            gf.DATA_SHARDS
+        for row in rows:
+            assert np.shares_memory(row, block)
+            assert np.shares_memory(gp.bytes_to_words(row), row)
+        if not any(b is block for b in blocks_seen):
+            blocks_seen.append(block)
+        return launched(encoder, coeff, rows)
+    monkeypatch.setattr(pl, "_transform_buffers_async", recording)
     stats: dict = {}
     assert pl.rebuild_ec_files(base, encoder=encoder,
                                buffer_size=buffer_size,
@@ -648,13 +669,16 @@ def test_rebuild_reuses_blocks(tmp_path, enc, buffer_size, batch_windows,
     for sid, data in want.items():
         with open(base + pl.to_ext(sid), "rb") as f:
             assert f.read() == data, sid
-    shard = len(want[0])
+    shard = len(want[lost[0]])
     windows = -(-shard // buffer_size)
     calls = len(lost) if sequential else 1
     blocks = -(-windows // batch_windows) * calls
     assert blocks >= 8 * calls
+    assert stats["launches"] == stats["dispatches"] == blocks
+    assert stats["windows"] == windows * calls
     assert stats["blocks_allocated"] + stats["blocks_reused"] == blocks
     assert stats["blocks_reused"] > 0
+    assert len(blocks_seen) == stats["blocks_allocated"]
     # reader, two queued, launcher, two queued, writer
     bound = 2 * pl._PIPE_DEPTH + 3 if pl._use_overlap(encoder) else 1
     assert stats["blocks_allocated"] <= bound * calls
@@ -700,21 +724,27 @@ def test_rebuild_block_outlives_its_readback(tmp_path, monkeypatch):
     import hashlib
     lost = (3,)
     base, want = _striped(tmp_path, lost)
-    launched = pl.transform_block_async
+    launched = pl._transform_buffers_async
     seen = []
 
-    def recording(encoder, coeff, block, stats=None):
-        before = hashlib.sha1(block.tobytes()).hexdigest()
-        thunk = launched(encoder, coeff, block, stats)
+    def digest(rows):
+        h = hashlib.sha1()
+        for r in rows:
+            h.update(r.tobytes())
+        return h.hexdigest()
+
+    def recording(encoder, coeff, rows):
+        before = digest(rows)
+        thunk = launched(encoder, coeff, rows)
 
         def readback():
             # leave the reader time to overwrite a block released early
             time.sleep(0.005)
             out = thunk()
-            seen.append((before, hashlib.sha1(block.tobytes()).hexdigest()))
+            seen.append((before, digest(rows)))
             return out
         return readback
-    monkeypatch.setattr(pl, "transform_block_async", recording)
+    monkeypatch.setattr(pl, "_transform_buffers_async", recording)
     stats: dict = {}
     assert pl.rebuild_ec_files(base, encoder=_encoder("jax"),
                                buffer_size=SB, batch_windows=1,
@@ -724,6 +754,25 @@ def test_rebuild_block_outlives_its_readback(tmp_path, monkeypatch):
     assert all(a == b for a, b in seen)
     with open(base + pl.to_ext(3), "rb") as f:
         assert f.read() == want[3]
+
+
+def test_rebuild_skips_the_block_wrapper(tmp_path, monkeypatch):
+    """A JaxEncoder rebuild never goes through the (B, k, L) block
+    wrapper and its device-side layout ops."""
+    from seaweedfs_tpu.ec import batch
+    from seaweedfs_tpu.ec.encoder_jax import JaxEncoder
+
+    def refuse(*a, **k):
+        raise AssertionError("rebuild went through the block wrapper")
+    base, want = _striped(tmp_path, (0, 12))
+    monkeypatch.setattr(pl, "transform_block_async", refuse)
+    monkeypatch.setattr(batch, "transform_block_async", refuse)
+    monkeypatch.setattr(JaxEncoder, "transform_batch", refuse)
+    assert pl.rebuild_ec_files(base, encoder=JaxEncoder(), buffer_size=SB,
+                               batch_windows=4) == [0, 12]
+    for sid, data in want.items():
+        with open(base + pl.to_ext(sid), "rb") as f:
+            assert f.read() == data, sid
 
 
 def test_concurrent_degraded_reads_time_the_lock(ec_fixture, fresh_tracing):
