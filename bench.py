@@ -149,7 +149,6 @@ def _verify_paths_on_device(n_small: int = 256 << 10) -> dict:
 
     from seaweedfs_tpu.ec import gf
     from seaweedfs_tpu.ec.encoder_cpu import CpuEncoder
-    from seaweedfs_tpu.ops import gf256_mxu as gm
     from seaweedfs_tpu.ops import gf256_pallas as gp
 
     rng = np.random.default_rng(3)
@@ -172,7 +171,6 @@ def _verify_paths_on_device(n_small: int = 256 << 10) -> dict:
     paths = {
         "vpu": lambda c, ws: gp.gf256_words_transform(
             gf.bitplane_constants(c), ws),
-        "mxu": gm.mxu_words_transform,
     }
     status: dict = {}
     for name, fn in paths.items():
@@ -368,7 +366,6 @@ def child_main() -> None:
     _emit({"stage": "probe", "probe_s": round(time.perf_counter() - t0, 2)})
 
     from seaweedfs_tpu.ec import gf
-    from seaweedfs_tpu.ops import gf256_mxu as gm
     from seaweedfs_tpu.ops import gf256_pallas as gp
 
     status = _verify_paths_on_device()
@@ -389,7 +386,6 @@ def child_main() -> None:
     paths = {
         "vpu": lambda c, ws: gp.gf256_words_transform(
             gf.bitplane_constants(c), ws),
-        "mxu": gm.mxu_words_transform,
     }
 
     max_bytes = int(os.environ.get("SWTPU_BENCH_BYTES", str(256 << 20)))

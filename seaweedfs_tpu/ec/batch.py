@@ -6,39 +6,32 @@ fast as its *scheduling*: window-at-a-time dispatch is latency-bound
 long before the field math matters (PAPERS.md 2108.02692 and 1312.5155
 draw the same conclusion at CPU scale). This module is the shared
 execution engine for the bulk EC paths — whole-volume encode
-(`pipeline.encode_volume`) and parity scrub (`EcVolume.verify_parity` /
-`ec/scrub.py`):
+(`pipeline.encode_volume`), parity scrub (`EcVolume.verify_parity` /
+`ec/scrub.py`) and whole-volume rebuild (`pipeline.rebuild_ec_files`):
 
-* gather **B stripe windows into one `(B, k, L)` uint8 block**;
-* run encode / verify as **ONE batched transform per block**
-  (`encoder.transform_batch`: the CPU backends flatten the
-  batch into the byte axis — the GF transform is columnwise, so the
-  batch dim is free — while `JaxEncoder` jits a vmapped bitplane
-  transform once per `(rows, k)` shape and shards the block along the
-  batch dim via `NamedSharding(P('batch'))` when more than one device
-  is attached);
+* gather **B stripe windows into one shard-major `(k, R)` uint8
+  block**: row i holds shard position i's windows back to back,
+  zero-padded to the words kernel's block quantum (`_WORD_QUANTUM`);
+* run encode / verify / rebuild as **ONE transform per block**: the
+  block's k rows go to the encoder's one launch,
+  `encoder.transform_async`, which on the JAX backend hands each row
+  to the kernel as a free (wm, 128) u32 view, with no device-side
+  layout work around it;
 * account every dispatch, pread and byte **deterministically** (the
   `stats` dicts below), so tools/bench_ec.py can gate the batching win
   on arithmetic instead of wall clock.
 
-Whole-volume rebuild (`pipeline.rebuild_ec_files`) batches its windows
-the same way — `window_blocks` and `clamp_batch_windows` size its blocks
-and it keeps the same `stats` — but gathers them shard-major, one
-contiguous row per survivor, and launches the rows through the
-pipeline's per-row `_transform_buffers_async`, so the block reaches the
-kernel as free u32 word views with no device-side layout work.
-
 The GF(256) transform is independent per byte column, so batching is
-*exact*: a `(B, k, L)` block transforms to the same bytes as B separate
-`(k, L)` windows — the numpy per-window oracle remains the byte-identity
-gate for every backend (tests/test_ec_batch.py).
+*exact*: a block of B windows transforms to the same bytes as B
+separate windows — the numpy per-window oracle remains the
+byte-identity gate for every backend (tests/test_ec_batch.py).
 """
 
 from __future__ import annotations
 
 import numpy as np
 
-from ..util import tracing
+from . import gf
 
 # windows gathered per transform dispatch: enough to amortise dispatch
 # latency into noise, small enough that python-side window bookkeeping
@@ -59,6 +52,16 @@ DEFAULT_BATCH_WINDOWS = 8
 # the background scrubber passes its own tighter budget because for
 # it the bound is an I/O *burst* limit, not only memory.
 BLOCK_BYTE_BUDGET = 128 << 20
+
+# the GF words kernel's block quantum in bytes (ops/gf256_pallas.py:
+# _DEFAULT_BM rows of 128 u32 words): a byte row of a multiple of it
+# views as (wm, 128) u32 words with no copy (`bytes_to_words`)
+_WORD_QUANTUM = 256 * 512
+
+
+def _words_len(n: int) -> int:
+    """`n` bytes rounded up to the words kernel's block quantum."""
+    return -(-n // _WORD_QUANTUM) * _WORD_QUANTUM
 
 
 def clamp_batch_windows(batch_windows: int, window_bytes: int,
@@ -84,47 +87,45 @@ def add_stat(stats: dict | None, **kv) -> None:
         stats[k] = stats.get(k, 0) + v
 
 
-def transform_block(encoder, coeff: np.ndarray, block: np.ndarray,
-                    stats: dict | None = None) -> np.ndarray:
-    """Apply a (rows, k) GF(256) coefficient matrix to a (B, k, L)
-    window block in ONE dispatch -> (B, rows, L) uint8."""
-    return transform_block_async(encoder, coeff, block, stats)()
-
-
 def transform_block_async(encoder, coeff: np.ndarray, block: np.ndarray,
                           stats: dict | None = None):
-    """Launch the batched transform; returns a thunk yielding the
-    (B, rows, L) numpy result. On the JAX backend the dispatch is
-    asynchronous and the thunk blocks on readback, so the caller can
-    overlap the NEXT block's preads with this block's device time —
-    the same double-buffering contract as pipeline's per-window
-    `_transform_buffers_async`, now per B windows."""
-    block = np.asarray(block, np.uint8) if not hasattr(block, "devices") \
-        else block
-    add_stat(stats, dispatches=1, batches=1, windows=int(block.shape[0]),
-             bytes_in=int(block.nbytes))
-    out = encoder.transform_batch(coeff, block)
+    """Apply a (rows, k) coefficient matrix to a shard-major (k, R)
+    block as ONE dispatch: row i holds shard position i's windows back
+    to back, and R is a multiple of `_WORD_QUANTUM`, so every row
+    reaches the device as a free word view. Returns the encoder's
+    thunk, which yields the `rows` output rows of R bytes; on the JAX
+    backend the launch is asynchronous and the thunk blocks on
+    readback, so the caller can overlap the NEXT block's preads with
+    this block's device time.
 
-    def readback() -> np.ndarray:
-        with tracing.start("ec", "readback") as sp:
-            got = np.asarray(out)
-            sp.nbytes = got.nbytes
-        return got
-    return readback
+    Counts the dispatch and the batch; the caller, which alone knows
+    its windows, counts `windows` and `bytes_in`."""
+    add_stat(stats, dispatches=1, batches=1)
+    return encoder.transform_async(coeff, list(block))
 
 
-def verify_block(encoder, block: np.ndarray,
+def verify_block(encoder, block: np.ndarray, count: int,
                  stats: dict | None = None) -> list[bool]:
-    """Recompute parity for a (B, k+m, L) block and compare against its
-    stored parity rows in ONE dispatch -> per-window verdicts.
+    """Recompute parity over a shard-major (k+m, count x size) block of
+    `count` windows in ONE dispatch and compare it with the block's
+    stored parity rows window by window -> per-window verdicts.
 
     Zero-padded tail windows verify clean by construction: parity of
     all-zero data is all-zero, which is exactly what a shard read past
     EOF returns for the stored rows."""
-    block = np.asarray(block, np.uint8)
-    add_stat(stats, dispatches=1, batches=1, windows=int(block.shape[0]),
-             bytes_in=int(block.nbytes))
-    return [bool(ok) for ok in encoder.verify_batch(block)]
+    k = gf.DATA_SHARDS
+    n = block.shape[1]
+    add_stat(stats, windows=count, bytes_in=int(block.nbytes))
+    data = block[:k]
+    if n % _WORD_QUANTUM:
+        data = np.zeros((k, _words_len(n)), np.uint8)
+        data[:, :n] = block[:k]
+    parity = transform_block_async(encoder, gf.parity_matrix(), data,
+                                   stats)()
+    ok = np.ones(count, bool)
+    for got, stored in zip(parity, block[k:]):
+        ok &= (got[:n] == stored).reshape(count, -1).all(axis=1)
+    return ok.tolist()
 
 
 def localize_corrupt_rows(encoder, rows: np.ndarray) -> "list[int]":
@@ -144,22 +145,17 @@ def localize_corrupt_rows(encoder, rows: np.ndarray) -> "list[int]":
     Cost: total_shards reconstruct+verify passes over ONE window, paid
     only for corrupt windows — rot is rare by construction.
     """
-    from . import gf
+    from .pipeline import _transform_buffers
 
     total = int(rows.shape[0])
-    k = gf.DATA_SHARDS
     culprits: "list[int]" = []
     for c in range(total):
-        sources = [s for s in range(total) if s != c][:k]
+        sources = [s for s in range(total) if s != c][:gf.DATA_SHARDS]
         coeff = gf.cached_shard_rows((c,), tuple(sources))
-        from .pipeline import _transform_buffers
-        rec = _transform_buffers(encoder, coeff,
-                                 [np.ascontiguousarray(rows[s])
-                                  for s in sources])[0]
         cand = np.array(rows, np.uint8, copy=True)
-        cand[c] = np.frombuffer(
-            np.asarray(rec, np.uint8).tobytes(), np.uint8)
-        if bool(encoder.verify_batch(cand[None, :, :])[0]):
+        cand[c] = _transform_buffers(encoder, coeff,
+                                     [rows[s] for s in sources])[0]
+        if verify_block(encoder, cand, 1)[0]:
             culprits.append(c)
     return culprits if len(culprits) == 1 else []
 

@@ -454,9 +454,10 @@ class EcVolume:
                           strict: bool = False,
                           stats: dict | None = None) -> np.ndarray:
         """Gather `count` consecutive stripe windows of `size` bytes
-        into one (count, 14, size) uint8 block — the scrub unit of the
+        into one shard-major (14, count x size) uint8 block — row i
+        holds shard i's windows back to back, the scrub unit of the
         stripe-batch engine. ONE pread (or one remote fetch) per shard
-        covers the whole block; rows past shard EOF read as zeros, and
+        covers the whole block; bytes past shard EOF read as zeros, and
         since the stored parity there is zeros too, padded tail windows
         verify clean by construction.
 
@@ -476,7 +477,7 @@ class EcVolume:
         scrub soak uses to prove planted corruption is detected while
         foreground reads stay clean."""
         nbytes = count * size
-        rows = []
+        block = np.empty((gf.TOTAL_SHARDS, nbytes), np.uint8)
         local_preads = 0
         remote_rows = 0
         for sid in range(gf.TOTAL_SHARDS):
@@ -493,27 +494,26 @@ class EcVolume:
                         f"{offset} has no evidence for it")
             else:
                 data = self._read_shard_interval(sid, offset, nbytes)
-            if len(data) < nbytes:
-                data = data + b"\x00" * (nbytes - len(data))
-            rows.append(np.frombuffer(data, np.uint8).reshape(count, size))
+            got = min(len(data), nbytes)
+            block[sid, :got] = np.frombuffer(data, np.uint8, got)
+            block[sid, got:] = 0
         from .batch import add_stat
         # preads = LOCAL shard reads only; rows served by a peer (or
         # reconstructed) are accounted as remote_rows — a degraded
         # volume's verify report must not claim disk reads it never did
         add_stat(stats, preads=local_preads, remote_rows=remote_rows,
                  bytes_read=nbytes * gf.TOTAL_SHARDS)
-        block = np.stack(rows, axis=1)
         if failpoints.armed():
             # window-major, sid-ascending — the exact fire order of the
             # pre-batching per-window path, so `flip:N` grammars plant
             # corruption in the same windows batched or not
-            for w in range(count):
+            for w in range(0, nbytes, size):
                 for sid in range(gf.TOTAL_SHARDS):
                     d = failpoints.corrupt("scrub.read",
-                                           block[w, sid].tobytes())
+                                           block[sid, w:w + size].tobytes())
                     if len(d) != size:  # truncate armed: keep row shape
                         d = d[:size] + b"\x00" * (size - len(d))
-                    block[w, sid] = np.frombuffer(d, np.uint8)
+                    block[sid, w:w + size] = np.frombuffer(d, np.uint8)
         return block
 
     def verify_window_block(self, offset: int, count: int, size: int,
@@ -522,11 +522,11 @@ class EcVolume:
         """Recompute RS(10,4) parity over `count` consecutive stripe
         windows in ONE batched transform dispatch and compare against
         the stored parity rows -> per-window verdicts. Every backend
-        answers through the same `verify_batch(block)` surface — no
-        per-encoder branching."""
+        answers through the encoders' one launch — no per-encoder
+        branching."""
         from .batch import verify_block
         block = self.read_window_block(offset, count, size, strict, stats)
-        return verify_block(self.encoder(count * size), block, stats)
+        return verify_block(self.encoder(count * size), block, count, stats)
 
     def verify_window(self, offset: int, size: int,
                       strict: bool = False) -> bool:

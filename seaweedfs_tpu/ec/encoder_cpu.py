@@ -67,59 +67,14 @@ class CpuEncoder:
             out.append(acc)
         return out
 
-    # -- batched API (stripe-batch engine, ec/batch.py) -------------------
-
-    def transform_batch(self, coeff: np.ndarray,
-                        block: np.ndarray) -> np.ndarray:
-        """Apply a (rows, k) coefficient matrix to a (B, k, L) window
-        block in ONE vectorized call -> (B, rows, L).
-
-        The GF(256) transform is independent per byte column, so the
-        batch dim is free: the numpy path runs each table lookup over
-        the whole (B, L) plane at once (one numpy op per coefficient
-        for the entire block), while the native AVX2 kernel walks the
-        contiguous per-window row views — window-sized streams stay
-        L2-resident, which measures ~2x faster than flattening the
-        batch into one long per-position stream. Either way the block
-        costs one engine dispatch, and the bytes are identical to B
-        separate per-window transforms."""
-        coeff = np.asarray(coeff, np.uint8)
-        block = np.ascontiguousarray(block, dtype=np.uint8)
-        bsz, k, n = block.shape
-        rows = coeff.shape[0]
-        assert k == coeff.shape[1], (coeff.shape, block.shape)
-        if self.use_native and bsz and n:
-            from ..native import gf256 as _native
-            out = np.empty((bsz, rows, n), np.uint8)
-            for b in range(bsz):
-                for r, row in enumerate(_native.transform(
-                        coeff, [block[b, i] for i in range(k)])):
-                    out[b, r] = row
-            return out
-        outs = self._apply_numpy(coeff, [block[:, i, :] for i in range(k)])
-        return np.stack(outs, axis=1)
-
-    def encode_batch(self, block: np.ndarray) -> np.ndarray:
-        """(B, k, L) data windows -> (B, k+m, L) full shard windows."""
-        block = np.asarray(block, np.uint8)
-        parity = self.transform_batch(self.parity, block)
-        return np.concatenate([block, parity], axis=1)
-
-    def verify_batch(self, block: np.ndarray) -> np.ndarray:
-        """(B, k+m, L) stored windows -> (B,) bool verdicts, one
-        parity recompute dispatch for the whole block."""
-        block = np.asarray(block, np.uint8)
-        par = self.transform_batch(self.parity, block[:, :self.k, :])
-        return (par == block[:, self.k:, :]).all(axis=(1, 2))
-
-    def reconstruct_batch(self, present_rows: list[int],
-                          want_rows: list[int],
-                          block: np.ndarray) -> np.ndarray:
-        """Rebuild want_rows for every window of a (B, k, L) block of
-        present shards (stacked in present_rows order) -> (B, r, L)."""
-        coeff = gf.cached_shard_rows(tuple(want_rows),
-                                     tuple(present_rows), self.k, self.n)
-        return self.transform_batch(coeff, block)
+    def transform_async(self, coeff: np.ndarray, rows: list):
+        """The encoders' one launch: out[r] = XOR_i gf_mul(coeff[r, i],
+        rows[i]) over equal-length uint8 rows. The host computes it
+        eagerly (native AVX2 kernel when built, numpy table lookups
+        otherwise); the returned thunk only hands the rows back."""
+        out = self._apply(np.asarray(coeff, np.uint8),
+                          [np.asarray(r, np.uint8) for r in rows])
+        return lambda: out
 
     # -- public API -------------------------------------------------------
 
@@ -133,10 +88,9 @@ class CpuEncoder:
         return data + parity
 
     def verify(self, shards) -> bool:
-        """The unified backend verify: accepts a list of k+m equal-length
-        rows OR a stacked (k+m, L) uint8 array (every backend answers
-        the same `verify(block) -> bool` — EcVolume.verify_window no
-        longer branches per encoder type)."""
+        """Recompute parity from shards[:k] and compare it with
+        shards[k:]: a list of k+m equal-length rows or a stacked
+        (k+m, L) uint8 array."""
         if len(shards) != self.n:
             return False
         data = [np.ascontiguousarray(s, dtype=np.uint8)

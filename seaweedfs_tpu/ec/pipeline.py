@@ -33,8 +33,9 @@ from ..storage import types as t
 from ..storage.needle_map import walk_index_blob, write_sorted_index
 from ..util import tracing
 from . import gf
-from .batch import (DEFAULT_BATCH_WINDOWS, add_stat, clamp_batch_windows,
-                    transform_block_async, window_blocks)
+from .batch import (DEFAULT_BATCH_WINDOWS, _words_len, add_stat,
+                    clamp_batch_windows, transform_block_async,
+                    window_blocks)
 from .locate import LARGE_BLOCK_SIZE, SMALL_BLOCK_SIZE, n_large_block_rows
 
 # read-ahead / dispatch-ahead depth of the threaded encode pipeline: 2 is
@@ -48,12 +49,6 @@ try:
     _IOV_MAX = max(16, os.sysconf("SC_IOV_MAX"))
 except (AttributeError, ValueError, OSError):
     _IOV_MAX = 16
-
-
-# the GF words kernel's block quantum in bytes (ops/gf256_pallas.py:
-# _DEFAULT_BM rows of 128 u32 words): a byte row of a multiple of it
-# views as (wm, 128) u32 words with no copy (`bytes_to_words`)
-_WORD_QUANTUM = 256 * 512
 
 
 def to_ext(shard_id: int) -> str:
@@ -127,51 +122,11 @@ def backend_resolves_to_tpu(backend: str) -> bool:
 
 def _transform_buffers_async(encoder, coeff: np.ndarray,
                              buffers: list[np.ndarray]):
-    """Launch the GF transform and return a thunk that yields the output
-    byte buffers when called.
-
-    On the JAX path the device work is dispatched asynchronously — the
-    thunk blocks on readback, so the caller can overlap the NEXT batch's
-    file reads and transfers with this batch's kernel time (the reference
-    overlaps nothing: its 256KB loop at ec_encoder.go:114-186 is serial).
-    CPU encoders compute eagerly and the thunk is a no-op."""
-    if _use_overlap(encoder):  # the single async-dispatch predicate
-        import jax
-        from ..ops.gf256_pallas import (bytes_to_words, gf256_words_transform,
-                                        words_to_bytes)
-        n = len(buffers[0])
-        with tracing.start("ec", "h2d") as sp:
-            words = [jax.device_put(bytes_to_words(b)) for b in buffers]
-            sp.nbytes = n * len(buffers)
-        with tracing.start("ec", "launch") as sp:
-            if os.environ.get("SWTPU_EC_METHOD") == "mxu":
-                # MXU GF(2) bit-matrix formulation (ops/gf256_mxu.py); the
-                # default VPU Pallas kernel wins at small sizes, the MXU
-                # at large streams — bench.py races both
-                from ..ops.gf256_mxu import mxu_words_transform
-                outs = mxu_words_transform(np.asarray(coeff, np.uint8),
-                                           words)
-            else:
-                consts = gf.bitplane_constants(coeff)
-                outs = gf256_words_transform(consts, words)
-            sp.nbytes = n * len(buffers)
-
-        def readback() -> list[np.ndarray]:
-            with tracing.start("ec", "readback") as sp:
-                sp.nbytes = n * len(outs)
-                return [words_to_bytes(np.asarray(o), n).copy()
-                        for o in outs]
-        return readback
-    # CPU path: native AVX2 kernel when built, numpy table lookup otherwise
-    from .encoder_cpu import CpuEncoder
-    if isinstance(encoder, CpuEncoder):
-        out = encoder._apply(np.asarray(coeff, np.uint8),
-                             [np.asarray(b, np.uint8) for b in buffers])
-    else:
-        out = CpuEncoder._apply_numpy(np.asarray(coeff, np.uint8),
-                                      [np.asarray(b, np.uint8)
-                                       for b in buffers])
-    return lambda: out
+    """Launch the GF transform over equal-length host byte buffers and
+    return a thunk that yields the output byte buffers when called: the
+    encoder's one launch (`transform_async`), asynchronous on the JAX
+    backend and eager on the host encoders."""
+    return encoder.transform_async(coeff, buffers)
 
 
 def _transform_buffers(encoder, coeff: np.ndarray,
@@ -289,9 +244,8 @@ def _use_overlap(encoder) -> bool:
     GIL contention — measured 2x SLOWER on a single-core host — and the
     plain serial loop wins.
 
-    This is THE async-dispatch predicate: _transform_buffers_async
-    branches on it too, so the pipeline shape and the launch semantics
-    cannot diverge."""
+    This is THE async-dispatch predicate: JaxEncoder's transform_async
+    is the one launch that returns before the work is done."""
     try:
         from .encoder_jax import JaxEncoder
     except ImportError:  # jax-less host: CPU encoders only, eager
@@ -383,21 +337,24 @@ def encode_volume(base_name: str, encoder=None,
                   stats: dict | None = None) -> None:
     """Stripe <base>.dat into <base>.ec00 .. .ec13 (WriteEcFiles) with
     the stripe-batch engine: up to `batch_windows` stripe windows
-    gather into one (B, 10, buf) block and ONE transform dispatch
-    emits all four parity rows for every window in the block —
-    ceil(W/B) dispatches per uniform-window run instead of W
-    (ec/batch.py). A volume has at most two such runs: groups flush
-    once at the large->small block-size boundary (mixed window
-    lengths never share a block), so the whole-volume count is
-    bounded by ceil(W_large/B) + ceil(W_small/B) — the exact
-    ceil(W/B) the bench gates on holds when the buffer size divides
-    both areas into equal windows (its geometry).
+    gather into one shard-major (10, R) block — row i holds shard
+    position i's windows back to back, zero-padded to the words
+    kernel's block quantum — and ONE transform dispatch emits all four
+    parity rows for every window in the block — ceil(W/B) dispatches
+    per uniform-window run instead of W (ec/batch.py). A volume has at
+    most two such runs: groups flush once at the large->small
+    block-size boundary (mixed window lengths never share a block), so
+    the whole-volume count is bounded by ceil(W_large/B) +
+    ceil(W_small/B) — the exact ceil(W/B) the bench gates on holds
+    when the buffer size divides both areas into equal windows (its
+    geometry).
 
     Windows whose preads are contiguous in the .dat (consecutive
     buffers of the same block row) coalesce into one read per shard
     position, so the batch cuts syscalls the same ratio it cuts
-    dispatches. File I/O still overlaps the device transform via the
-    double-buffered reader/writer threads (`_run_overlapped`);
+    dispatches. Each data row and each parity row goes to its shard
+    file as one write. File I/O still overlaps the device transform
+    via the double-buffered reader/writer threads (`_run_overlapped`);
     `stats` accumulates the deterministic accounting
     (windows/batches/dispatches/preads/bytes) tools/bench_ec.py
     gates on."""
@@ -430,11 +387,13 @@ def encode_volume(base_name: str, encoder=None,
             yield first, pending
 
     def read_block(group):
-        """One (B, 10, buf) block read straight into its final array;
+        """One shard-major (10, R) block read straight into its rows;
         contiguous window reads coalesce into single preads per shard
-        position (no second joined-bytes copy is kept alive)."""
+        position. Returns (block, preads, take): the first `take`
+        bytes of each row are the group's windows, the rest is pad."""
         buf = group[0][2]
-        block = np.empty((len(group), gf.DATA_SHARDS, buf), np.uint8)
+        take = len(group) * buf
+        block = np.empty((gf.DATA_SHARDS, _words_len(take)), np.uint8)
         preads = got = 0
         with tracing.start("ec", "pread") as sp:
             for i in range(gf.DATA_SHARDS):
@@ -447,45 +406,41 @@ def encode_volume(base_name: str, encoder=None,
                         runs.append([off, buf])
                 w = 0
                 for off, ln in runs:
-                    f.seek(off)
-                    raw = f.read(ln)
-                    got += len(raw)
-                    if len(raw) < ln:
-                        raw += b"\x00" * (ln - len(raw))
-                    n = ln // buf
-                    block[w:w + n, i, :] = np.frombuffer(
-                        raw, np.uint8).reshape(n, buf)
-                    w += n
+                    r = _pread_into(f.fileno(), [block[i, w:w + ln]], off)
+                    got += r
+                    # past the .dat's end the stripe reads as zeros
+                    block[i, w + r:w + ln] = 0
+                    w += ln
                 preads += len(runs)
+            block[:, take:] = 0
             sp.nbytes = got
-        return block, preads
+        return block, preads, take
 
     def batches():
         for first, group in groups():
             specs = range(first, first + len(group))
             for i in specs:
                 rows.reading(i)
-            yield (*read_block(group), specs)
+            yield (*read_block(group), len(group), specs)
 
     def launch(item):
-        block, preads, specs = item
-        add_stat(stats, preads=preads, bytes_read=int(block.nbytes))
-        _count_encoded(block.nbytes)
+        block, preads, take, count, specs = item
+        nbytes = take * gf.DATA_SHARDS
+        add_stat(stats, preads=preads, bytes_read=nbytes, windows=count,
+                 bytes_in=nbytes)
+        _count_encoded(nbytes)
         thunk = transform_block_async(encoder, parity, block, stats)
         for i in specs:
             rows.enqueued(i)
         return item, thunk
 
     def write_result(item, thunk):
-        block = item[0]
-        parities = thunk()      # (B, m, buf)
+        block, _, take = item[:3]
+        parities = thunk()      # one row per parity shard
         with tracing.start("ec", "write") as sp:
-            for i in range(gf.DATA_SHARDS):
-                outs[i].write(np.ascontiguousarray(block[:, i, :]).tobytes())
-            for p in range(gf.PARITY_SHARDS):
-                outs[gf.DATA_SHARDS + p].write(
-                    np.ascontiguousarray(parities[:, p, :]).tobytes())
-            sp.nbytes = block.nbytes + parities.nbytes
+            for out, row in zip(outs, [*block, *parities]):
+                out.write(row[:take])
+            sp.nbytes = take * gf.TOTAL_SHARDS
 
     try:
         _run_overlapped(batches(), launch, write_result,
@@ -667,11 +622,6 @@ def _pread_into(fd: int, rows: list[np.ndarray], pos: int) -> int:
         if n:
             views[i] = views[i][n:]
     return got
-
-
-def _words_len(n: int) -> int:
-    """`n` bytes rounded up to the words kernel's block quantum."""
-    return -(-n // _WORD_QUANTUM) * _WORD_QUANTUM
 
 
 def _rebuild_rows(base_name: str, encoder, targets: list[int],

@@ -342,8 +342,9 @@ class Scrubber:
                 # never block the event loop mid-cycle
                 verify_task = asyncio.ensure_future(
                     tracing.run_in_executor(
-                        lambda b=block, n=count * self.window_bytes:
-                        verify_block(ev.encoder(n), b)))
+                        lambda b=block, c=count:
+                        verify_block(ev.encoder(c * self.window_bytes),
+                                     b, c)))
                 nxt, read_err = None, None
                 if wi < n_windows:
                     try:
@@ -381,12 +382,14 @@ class Scrubber:
                     # test over the block row slice we already hold):
                     # the structured report the autopilot repairs
                     # from. [] = ambiguous — the consumer must defer.
+                    rows = block[:, i * self.window_bytes:
+                                 (i + 1) * self.window_bytes]
                     try:
                         # encoder resolved INSIDE the thunk, like the
                         # verify dispatch: lazy backend init must not
                         # block the event loop mid-cycle
                         sids = await tracing.run_in_executor(
-                            lambda r=block[i]: localize_corrupt_rows(
+                            lambda r=rows: localize_corrupt_rows(
                                 ev.encoder(self.window_bytes), r))
                     except Exception as e:  # noqa: BLE001 —
                         # localization is advisory evidence; its

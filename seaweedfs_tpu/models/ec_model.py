@@ -14,11 +14,53 @@ Reference equivalents: reedsolomon Encode/Reconstruct at ec_encoder.go:192,
 
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
 from ..ec import gf
-from ..ec.encoder_jax import _apply_bitplanes, _default_use_pallas
+
+
+def _default_use_pallas() -> bool:
+    """The Pallas kernel on the TPU, the XLA bitplane transform on the
+    CPU. A backend-init error raises: it must never quietly select the
+    CPU formulation on a host that has a chip."""
+    return jax.default_backend() == "tpu"
+
+
+def _apply_bitplanes(consts: np.ndarray, data: jax.Array) -> jax.Array:
+    """out[..., r, :] = XOR_i gf_mul(coeff[r, i], data[..., i, :]) as
+    plain XLA ops (the steps' CPU formulation).
+
+    consts: (rows, k, 8) uint8 bitplane constants (host numpy, becomes a
+            compile-time constant).
+    data:   (..., k, n) uint8 shard bytes.
+    returns (..., rows, n) uint8.
+    """
+    rows, k, _ = consts.shape
+    out = []
+    for r in range(rows):
+        acc = None
+        for i in range(k):
+            row = consts[r, i]
+            if not row.any():
+                continue
+            x = data[..., i, :]
+            term = None
+            for j in range(8):
+                cj = int(row[j])
+                if cj == 0:
+                    continue
+                # 0x00/0xFF mask of bit j of every byte of shard i
+                mask = ((x >> j) & 1) * jnp.uint8(0xFF)
+                t = mask & jnp.uint8(cj)
+                term = t if term is None else term ^ t
+            if term is None:
+                continue
+            acc = term if acc is None else acc ^ term
+        out.append(acc if acc is not None
+                   else jnp.zeros(data.shape[:-2] + (data.shape[-1],), jnp.uint8))
+    return jnp.stack(out, axis=-2)
 
 
 def make_encode_step(use_pallas: bool | None = None):

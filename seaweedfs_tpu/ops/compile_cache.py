@@ -1,6 +1,6 @@
 """JAX's persistent compilation cache, placed once by each device entry
 point (chip_smoke.py, the volume server with a TPU EC backend, the bench
-child, tools/bench_pipeline.py). Library code never calls this: the
+child). Library code never calls this: the
 cache directory and compile-time floor are process-wide settings.
 
 Where JAX_COMPILATION_CACHE_DIR is set, JAX reads it as its own

@@ -1,8 +1,8 @@
 """Stripe-batch engine property tests: random window batches and random
 missing-sets through every available backend (cpu-numpy / cpu-native /
 jax) must be byte-identical to the per-window numpy oracle — encode,
-verify verdicts, and reconstruction alike (ec/batch.py + the batched
-encoder surface). Runs under JAX_PLATFORMS=cpu in tier-1; jax and the
+verify verdicts, and reconstruction alike (ec/batch.py + the encoders'
+one launch). Runs under JAX_PLATFORMS=cpu in tier-1; jax and the
 native kernel skip cleanly when unavailable."""
 
 import hashlib
@@ -34,7 +34,7 @@ def make_encoder(name):
     jax = pytest.importorskip("jax")
     del jax
     from seaweedfs_tpu.ec.encoder_jax import JaxEncoder
-    return JaxEncoder(use_pallas=False)
+    return JaxEncoder()
 
 
 @pytest.fixture(params=BACKENDS)
@@ -52,6 +52,16 @@ def _oracle_full(oracle, block):
     return np.stack([np.stack(oracle.encode(list(w))) for w in block])
 
 
+def _shard_major(windows, pad=True):
+    """(B, rows, L) windows -> (rows, B*L): row i holds every window's
+    row i back to back, zero-padded to the word quantum when `pad`."""
+    bsz, rows, n = windows.shape
+    width = ecb._words_len(bsz * n) if pad else bsz * n
+    block = np.zeros((rows, width), np.uint8)
+    block[:, :bsz * n] = windows.transpose(1, 0, 2).reshape(rows, -1)
+    return block
+
+
 # ---------------------------------------------------------------------
 # encode
 # ---------------------------------------------------------------------
@@ -62,18 +72,19 @@ def test_batch_encode_matches_perwindow_oracle(backend, oracle):
     # batch sizes incl. B=1 and non-multiples of device counts; window
     # lengths incl. odd (not a block-quantum multiple)
     for bsz, n in [(1, 512), (3, 1000), (8, 4096), (5, 64)]:
-        block = rng.integers(0, 256, (bsz, gf.DATA_SHARDS, n)
-                             ).astype(np.uint8)
-        want = _oracle_full(oracle, block)
-        got = np.asarray(enc.encode_batch(block))
-        assert got.shape == (bsz, gf.TOTAL_SHARDS, n), (name, got.shape)
-        assert np.array_equal(got, want), (name, bsz, n)
+        windows = rng.integers(0, 256, (bsz, gf.DATA_SHARDS, n)
+                               ).astype(np.uint8)
+        want = _shard_major(_oracle_full(oracle, windows), pad=False)
         # the engine counts exactly one dispatch per block
         stats = {}
-        par = ecb.transform_block(enc, gf.parity_matrix(), block, stats)
-        assert np.array_equal(par, want[:, gf.DATA_SHARDS:, :])
-        assert stats == {"dispatches": 1, "batches": 1, "windows": bsz,
-                         "bytes_in": block.nbytes}
+        par = ecb.transform_block_async(enc, gf.parity_matrix(),
+                                        _shard_major(windows), stats)()
+        assert len(par) == gf.PARITY_SHARDS, name
+        for got, row in zip(par, want[gf.DATA_SHARDS:]):
+            assert np.array_equal(got[:bsz * n], row), (name, bsz, n)
+            # the pad's parity is the parity of zeros
+            assert not got[bsz * n:].any(), (name, bsz, n)
+        assert stats == {"dispatches": 1, "batches": 1}
 
 
 # ---------------------------------------------------------------------
@@ -83,9 +94,13 @@ def test_batch_encode_matches_perwindow_oracle(backend, oracle):
 def test_batch_verify_localizes_random_corruption(backend, oracle):
     name, enc = backend
     rng = np.random.default_rng(202)
-    block = rng.integers(0, 256, (6, gf.DATA_SHARDS, 777)).astype(np.uint8)
-    full = _oracle_full(oracle, block)
-    assert ecb.verify_block(enc, full) == [True] * 6, name
+    windows = rng.integers(0, 256, (6, gf.DATA_SHARDS, 777)
+                           ).astype(np.uint8)
+    full = _shard_major(_oracle_full(oracle, windows), pad=False)
+    stats = {}
+    assert ecb.verify_block(enc, full, 6, stats) == [True] * 6, name
+    assert stats == {"dispatches": 1, "batches": 1, "windows": 6,
+                     "bytes_in": full.nbytes}
     for _ in range(8):
         bad = full.copy()
         hits = sorted({int(rng.integers(0, 6))
@@ -93,26 +108,26 @@ def test_batch_verify_localizes_random_corruption(backend, oracle):
         for w in hits:
             sid = int(rng.integers(0, gf.TOTAL_SHARDS))
             off = int(rng.integers(0, 777))
-            bad[w, sid, off] ^= int(rng.integers(1, 256))
-        verdicts = ecb.verify_block(enc, bad)
+            bad[sid, w * 777 + off] ^= int(rng.integers(1, 256))
+        verdicts = ecb.verify_block(enc, bad, 6)
         assert verdicts == [w not in hits for w in range(6)], \
             (name, hits, verdicts)
 
 
 def test_unified_verify_signature(backend, oracle):
-    """Satellite: every backend answers the same verify(block) -> bool
-    for a list of rows AND a stacked array — the shape
-    EcVolume.verify_window relies on with no per-encoder branching."""
+    """Every backend answers the same verify_block(block, count) ->
+    verdicts, and localize_corrupt_rows pins one rotten row to its
+    shard the same way on each — the calls EcVolume and the scrubber
+    make with no per-encoder branching."""
     name, enc = backend
     rng = np.random.default_rng(303)
     window = rng.integers(0, 256, (gf.DATA_SHARDS, 640)).astype(np.uint8)
     full = np.stack(oracle.encode(list(window)))
-    assert bool(enc.verify(full)) is True, name
-    assert bool(enc.verify([r for r in full])) is True, name
+    assert ecb.verify_block(enc, full, 1) == [True], name
     bad = full.copy()
     bad[11, 3] ^= 0x40
-    assert bool(enc.verify(bad)) is False, name
-    assert bool(enc.verify([r for r in bad])) is False, name
+    assert ecb.verify_block(enc, bad, 1) == [False], name
+    assert ecb.localize_corrupt_rows(enc, bad) == [11], name
 
 
 # ---------------------------------------------------------------------
@@ -125,9 +140,10 @@ def test_batch_reconstruct_random_missing_sets(backend, oracle):
     backend, for every window of the batch."""
     name, enc = backend
     rng = np.random.default_rng(404)
-    block = rng.integers(0, 256, (4, gf.DATA_SHARDS, 1536)
-                         ).astype(np.uint8)
-    full = _oracle_full(oracle, block)
+    windows = rng.integers(0, 256, (4, gf.DATA_SHARDS, 1536)
+                           ).astype(np.uint8)
+    full = _shard_major(_oracle_full(oracle, windows))
+    n = 4 * 1536
     cases = [(0,), (13,), (0, 1, 2, 3), (10, 11, 12, 13), (0, 5, 11, 13)]
     for _ in range(10):
         m = int(rng.integers(1, gf.PARITY_SHARDS + 1))
@@ -136,10 +152,10 @@ def test_batch_reconstruct_random_missing_sets(backend, oracle):
     for missing in cases:
         present = [i for i in range(gf.TOTAL_SHARDS)
                    if i not in missing][:gf.DATA_SHARDS]
-        rec = np.asarray(enc.reconstruct_batch(present, list(missing),
-                                               full[:, present, :]))
-        assert np.array_equal(rec, full[:, list(missing), :]), \
-            (name, missing)
+        coeff = gf.cached_shard_rows(tuple(missing), tuple(present))
+        rec = ecb.transform_block_async(enc, coeff, full[present])()
+        for got, sid in zip(rec, missing):
+            assert np.array_equal(got[:n], full[sid, :n]), (name, missing)
 
 
 # ---------------------------------------------------------------------
@@ -147,21 +163,49 @@ def test_batch_reconstruct_random_missing_sets(backend, oracle):
 # ---------------------------------------------------------------------
 
 LB, SB = 16 * 1024, 1024
+Q = ecb._WORD_QUANTUM
+# (large block, small block, scrub window): 1 KB windows pad every
+# block's rows to the word quantum; windows of the quantum pad none
+GEOMETRY = {"unaligned": (LB, SB, 4 * 1024), "aligned": (2 * Q, Q, Q)}
+
+
+def _volume(tmp_path_factory, large_block, small_block, needle_bytes):
+    """A volume of random needles big enough to span BOTH striping
+    areas: >= 1 large-block row (where consecutive windows are
+    contiguous per shard and preads coalesce) plus a small-block tail;
+    striped by the CPU encoder, with its .ecx."""
+    d = str(tmp_path_factory.mktemp("ecbatch"))
+    v = Volume(d, "", 9)
+    rng = random.Random(7)
+    lo, hi = needle_bytes
+    total, i = 0, 0
+    while total < 10 * (large_block + 2 * small_block) + 5000:
+        i += 1
+        size = rng.randint(lo, hi)
+        v.write_needle(Needle(cookie=i, id=i, data=rng.randbytes(size)))
+        total += size
+    v.close()
+    base = os.path.join(d, "9")
+    pl.encode_volume(base, encoder=pl.get_encoder("cpu"),
+                     large_block=large_block, small_block=small_block,
+                     buffer_size=small_block)
+    pl.write_sorted_file_from_idx(base)
+    return d, base
 
 
 @pytest.fixture(scope="module")
 def small_volume(tmp_path_factory):
-    d = str(tmp_path_factory.mktemp("ecbatch"))
-    v = Volume(d, "", 9)
-    rng = random.Random(7)
-    # big enough to span BOTH striping areas: >= 2 large-block rows
-    # (where consecutive windows are contiguous per shard and preads
-    # coalesce) plus a small-block tail
-    for i in range(1, 120):
-        v.write_needle(Needle(cookie=i, id=i,
-                              data=rng.randbytes(rng.randint(2000, 5000))))
-    v.close()
-    return d, os.path.join(d, "9")
+    return _volume(tmp_path_factory, LB, SB, (2000, 5000))
+
+
+@pytest.fixture(scope="module")
+def quantum_volume(tmp_path_factory):
+    return _volume(tmp_path_factory, 2 * Q, Q, (20000, 50000))
+
+
+def _geometry_volume(request, geometry):
+    return request.getfixturevalue(
+        "small_volume" if geometry == "unaligned" else "quantum_volume")
 
 
 def _shard_digest(base):
@@ -172,36 +216,40 @@ def _shard_digest(base):
     return h.hexdigest()
 
 
-def test_encode_volume_batched_is_byte_identical(small_volume):
-    d, base = small_volume
+@pytest.mark.parametrize("geometry", list(GEOMETRY))
+def test_encode_volume_batched_is_byte_identical(request, geometry):
+    d, base = _geometry_volume(request, geometry)
+    lb, sb, _ = GEOMETRY[geometry]
+    want = _shard_digest(base)
     sums, stats = {}, {}
     for bw in (1, 8):
         s: dict = {}
         pl.encode_volume(base, encoder=pl.get_encoder("cpu"),
-                         large_block=LB, small_block=SB, buffer_size=SB,
+                         large_block=lb, small_block=sb, buffer_size=sb,
                          batch_windows=bw, stats=s)
         sums[bw], stats[bw] = _shard_digest(base), s
-    assert sums[1] == sums[8]
+    assert sums[1] == sums[8] == want
     w = stats[1]["windows"]
     assert stats[1]["dispatches"] == w
     assert stats[8]["dispatches"] <= -(-w // 8)
     assert stats[8]["preads"] < stats[1]["preads"]
-    pl.write_sorted_file_from_idx(base)
+    # the unpadded bytes: ten rows of every window, at either width
+    assert stats[1]["bytes_in"] == stats[8]["bytes_in"] == w * 10 * sb
 
 
-def test_verify_parity_batched_matches_perwindow(small_volume):
-    d, base = small_volume
-    if not os.path.exists(base + ".ecx"):
-        pl.write_sorted_file_from_idx(base)
-    window = 4 * 1024
-    ev = EcVolume(d, "", 9, large_block=LB, small_block=SB,
+@pytest.mark.parametrize("geometry", list(GEOMETRY))
+def test_verify_parity_batched_matches_perwindow(request, geometry):
+    d, base = _geometry_volume(request, geometry)
+    lb, sb, window = GEOMETRY[geometry]
+    ev = EcVolume(d, "", 9, large_block=lb, small_block=sb,
                   encoder=pl.get_encoder("cpu"))
+    p = base + pl.to_ext(11)
+    offs = (window + 3, 3 * window + 9)
     try:
         # plant rot in two windows of a parity shard (bytes no
         # foreground read visits)
-        p = base + pl.to_ext(11)
         with open(p, "r+b") as f:
-            for off in (window + 3, 3 * window + 9):
+            for off in offs:
                 f.seek(off)
                 b = f.read(1)
                 f.seek(off)
@@ -214,6 +262,12 @@ def test_verify_parity_batched_matches_perwindow(small_volume):
         assert rb["dispatches"] <= -(-r1["windows"] // 8)
         assert rb["preads"] < r1["preads"]
     finally:
+        with open(p, "r+b") as f:
+            for off in offs:
+                f.seek(off)
+                b = f.read(1)
+                f.seek(off)
+                f.write(bytes([b[0] ^ 0x55]))
         ev.close()
 
 

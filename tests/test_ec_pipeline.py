@@ -319,9 +319,9 @@ def test_auto_backend_raises_when_backend_init_fails(monkeypatch):
     monkeypatch.setattr(jax, "default_backend", broken)
     with pytest.raises(RuntimeError, match="initialize backend"):
         pl.get_encoder("auto")
-    from seaweedfs_tpu.ec import encoder_jax
+    from seaweedfs_tpu.models import ec_model
     with pytest.raises(RuntimeError, match="initialize backend"):
-        encoder_jax._default_use_pallas()
+        ec_model._default_use_pallas()
 
 
 @pytest.mark.parametrize("flag,platforms,want", [
@@ -642,8 +642,9 @@ def test_rebuild_reuses_blocks(tmp_path, monkeypatch, enc, buffer_size,
     striped, the rows are views of the block that view as kernel words
     with no copy, every block is either new or reused, and no call
     allocates more than its pipeline can hold."""
+    from seaweedfs_tpu.ec.batch import _WORD_QUANTUM
     from seaweedfs_tpu.ops import gf256_pallas as gp
-    assert pl._WORD_QUANTUM == gp._DEFAULT_BM * gp._BLOCK_BYTES
+    assert _WORD_QUANTUM == gp._DEFAULT_BM * gp._BLOCK_BYTES
     base, want = _striped(tmp_path, lost)
     encoder = _encoder(enc)
     launched = pl._transform_buffers_async
@@ -756,23 +757,93 @@ def test_rebuild_block_outlives_its_readback(tmp_path, monkeypatch):
         assert f.read() == want[3]
 
 
-def test_rebuild_skips_the_block_wrapper(tmp_path, monkeypatch):
-    """A JaxEncoder rebuild never goes through the (B, k, L) block
-    wrapper and its device-side layout ops."""
-    from seaweedfs_tpu.ec import batch
+@pytest.mark.parametrize("path", ["rebuild", "encode_volume",
+                                  "verify_window_block",
+                                  "write_ec_files_batched"])
+def test_bulk_paths_launch_word_rows(tmp_path, monkeypatch, path):
+    """Every bulk path hands JaxEncoder's one launch k 1-D uint8 rows,
+    each a whole number of the words kernel's block quanta (so each
+    views as kernel words with no copy), and gets back what the CPU
+    encoder computes from the same rows."""
+    from seaweedfs_tpu.ec.batch import _WORD_QUANTUM
+    from seaweedfs_tpu.ec.encoder_cpu import CpuEncoder
     from seaweedfs_tpu.ec.encoder_jax import JaxEncoder
 
-    def refuse(*a, **k):
-        raise AssertionError("rebuild went through the block wrapper")
-    base, want = _striped(tmp_path, (0, 12))
-    monkeypatch.setattr(pl, "transform_block_async", refuse)
-    monkeypatch.setattr(batch, "transform_block_async", refuse)
-    monkeypatch.setattr(JaxEncoder, "transform_batch", refuse)
-    assert pl.rebuild_ec_files(base, encoder=JaxEncoder(), buffer_size=SB,
-                               batch_windows=4) == [0, 12]
-    for sid, data in want.items():
-        with open(base + pl.to_ext(sid), "rb") as f:
-            assert f.read() == data, sid
+    launched = JaxEncoder.transform_async
+    calls = []
+
+    def recording(self, coeff, rows):
+        assert len(rows) == np.shape(coeff)[1] == gf.DATA_SHARDS
+        for row in rows:
+            assert row.dtype == np.uint8 and row.ndim == 1
+            assert len(row) % _WORD_QUANTUM == 0, len(row)
+        want = CpuEncoder(use_native=False).transform_async(coeff, rows)()
+        thunk = launched(self, coeff, rows)
+
+        def checked():
+            got = thunk()
+            assert [bytes(g) for g in got] == [bytes(w) for w in want]
+            calls.append(len(rows[0]))
+            return got
+        return checked
+    monkeypatch.setattr(JaxEncoder, "transform_async", recording)
+
+    def digests(base):
+        out = []
+        for sid in range(gf.TOTAL_SHARDS):
+            with open(base + pl.to_ext(sid), "rb") as f:
+                out.append(hashlib.sha256(f.read()).hexdigest())
+        return out
+
+    if path == "rebuild":
+        base, want = _striped(tmp_path, (0, 12))
+        assert pl.rebuild_ec_files(base, encoder=JaxEncoder(),
+                                   buffer_size=SB, batch_windows=4) == [0, 12]
+        for sid, data in want.items():
+            with open(base + pl.to_ext(sid), "rb") as f:
+                assert f.read() == data, sid
+    elif path == "encode_volume":
+        base, _ = _striped(tmp_path, ())
+        want = digests(base)
+        pl.encode_volume(base, encoder=JaxEncoder(), large_block=LB,
+                         small_block=SB, buffer_size=SB, batch_windows=8)
+        assert digests(base) == want
+    elif path == "verify_window_block":
+        base, _ = _striped(tmp_path, ())
+        with open(base + pl.to_ext(11), "r+b") as f:
+            f.seek(2 * SB + 5)
+            b = f.read(1)
+            f.seek(2 * SB + 5)
+            f.write(bytes([b[0] ^ 0x21]))
+        # EcVolume opens <dir>/<vid>.*: the stripe needs no needle index
+        vol = str(tmp_path / "7")
+        for ext in [pl.to_ext(sid) for sid in range(gf.TOTAL_SHARDS)]:
+            os.rename(base + ext, vol + ext)
+        with open(vol + ".ecx", "wb"):
+            pass
+        ev = EcVolume(str(tmp_path), "", 7, large_block=LB, small_block=SB,
+                      encoder=JaxEncoder())
+        try:
+            assert ev.verify_window_block(0, 4, SB) == \
+                [True, True, False, True]
+        finally:
+            ev.close()
+    else:
+        # the served engine joins whole buffers: at buffers of the
+        # quantum, as the cells stripe, every joined row is whole too
+        q = _WORD_QUANTUM
+        bases = _dat_files(tmp_path, [2 * q * 10 * 2 + q * 10 + 7,
+                                      q * 10 * 2])
+        want = []
+        for base in bases:
+            pl.encode_volume(base, encoder=CpuEncoder(), large_block=2 * q,
+                             small_block=q, buffer_size=q)
+            want.append(digests(base))
+        pl.write_ec_files_batched(bases, encoder=JaxEncoder(),
+                                  large_block=2 * q, small_block=q,
+                                  buffer_size=q)
+        assert [digests(b) for b in bases] == want
+    assert calls
 
 
 def test_concurrent_degraded_reads_time_the_lock(ec_fixture, fresh_tracing):

@@ -16,29 +16,33 @@ def rng():
 
 
 def test_jax_encode_matches_cpu(rng):
-    cpu, tpu = CpuEncoder(), JaxEncoder(use_pallas=False)
+    cpu, tpu = CpuEncoder(), JaxEncoder()
     data = rng.integers(0, 256, (10, 1024)).astype(np.uint8)
     want = cpu.encode([d for d in data])
-    got = np.asarray(tpu.encode(data))
-    assert got.shape == (14, 1024)
-    for i in range(14):
-        assert np.array_equal(got[i], want[i]), f"shard {i}"
+    got = tpu.transform_async(gf.parity_matrix(), list(data))()
+    assert len(got) == 4
+    for p in range(4):
+        assert got[p].dtype == np.uint8 and got[p].shape == (1024,)
+        assert np.array_equal(got[p], want[10 + p]), f"parity {p}"
 
 
 def test_jax_encode_batched(rng):
-    tpu = JaxEncoder(use_pallas=False)
+    """Three windows laid shard-major, each row the windows back to
+    back: one launch encodes every window at once."""
+    tpu = JaxEncoder()
     cpu = CpuEncoder()
     batch = rng.integers(0, 256, (3, 10, 256)).astype(np.uint8)
-    got = np.asarray(tpu.encode(batch))
-    assert got.shape == (3, 14, 256)
+    rows = batch.transpose(1, 0, 2).reshape(10, 3 * 256)
+    got = tpu.transform_async(gf.parity_matrix(), list(rows))()
     for b in range(3):
         want = cpu.encode([d for d in batch[b]])
-        for i in range(14):
-            assert np.array_equal(got[b, i], want[i])
+        for p in range(4):
+            assert np.array_equal(got[p][b * 256:(b + 1) * 256],
+                                  want[10 + p]), (b, p)
 
 
 def test_jax_reconstruct_subsets(rng):
-    tpu = JaxEncoder(use_pallas=False)
+    tpu = JaxEncoder()
     cpu = CpuEncoder()
     shards = cpu.encode([rng.integers(0, 256, 128).astype(np.uint8)
                          for _ in range(10)])
@@ -46,19 +50,23 @@ def test_jax_reconstruct_subsets(rng):
     # is trivial; all-4-losses-in-data is the hard one)
     for missing in [(0,), (13,), (0, 1, 2, 3), (10, 11, 12, 13),
                     (0, 5, 11, 13)]:
-        partial = [None if i in missing else shards[i] for i in range(14)]
-        out = tpu.reconstruct(partial)
-        for i in range(14):
-            assert np.array_equal(out[i], shards[i]), (missing, i)
+        use = [i for i in range(14) if i not in missing][:10]
+        coeff = gf.shard_rows(list(missing), use)
+        out = tpu.transform_async(coeff, [shards[i] for i in use])()
+        for row, i in zip(out, missing):
+            assert np.array_equal(row, shards[i]), (missing, i)
 
 
 def test_jax_verify(rng):
-    tpu = JaxEncoder(use_pallas=False)
+    from seaweedfs_tpu.ec.batch import verify_block
+
+    tpu = JaxEncoder()
     data = rng.integers(0, 256, (10, 64)).astype(np.uint8)
-    full = np.array(tpu.encode(data))
-    assert tpu.verify(full)
+    parity = tpu.transform_async(gf.parity_matrix(), list(data))()
+    full = np.concatenate([data, np.stack(parity)])
+    assert verify_block(tpu, full, 1) == [True]
     full[11, 3] ^= 0x40
-    assert not tpu.verify(full)
+    assert verify_block(tpu, full, 1) == [False]
 
 
 def test_pallas_interpret_matches_cpu(rng):

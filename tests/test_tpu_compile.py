@@ -64,29 +64,19 @@ REBUILD4 = gf.shard_rows([0, 1, 2, 3], list(range(4, gf.TOTAL_SHARDS)))
 
 @pytest.mark.parametrize("coeff", [ENCODE, REBUILD4],
                          ids=["encode", "rebuild4"])
-@pytest.mark.parametrize("shard_mb", [8, 64])
+@pytest.mark.parametrize("shard_mb", [8, 32, 64])
 def test_words_transform_compiles(one_chip, coeff, shard_mb):
     # 8 MB/shard: the stripe-batch block (8 windows x 1 MB) ec.encode
-    # dispatches for small rows; 64 MB/shard: its large-row flush (8
-    # buffers of 8 MB joined per shard) and chip_smoke's kernel check
+    # dispatches for small rows and ec.rebuild for every block; 32
+    # MB/shard: a scrub block (8 windows x 4 MB); 64 MB/shard: the
+    # large-row flush (8 buffers of 8 MB joined per shard) and
+    # chip_smoke's kernel check
     consts = gf.bitplane_constants(coeff)
     x = jax.ShapeDtypeStruct((shard_mb * MB // 512, 128), jnp.uint32,
                              sharding=one_chip)
     fn = jax.jit(lambda *ws: gp.gf256_words_transform(
         consts, list(ws), interpret=False))
     _check(fn.lower(*[x] * coeff.shape[1]).compile())
-
-
-def test_batch_transform_compiles(one_chip):
-    # JaxEncoder.transform_batch's Pallas branch as ec.rebuild and
-    # ec.verify dispatch it: a (10, 8 MB) uint8 block through the
-    # generic wrapper (layout change on device, then the words kernel)
-    consts = gf.bitplane_constants(REBUILD4)
-    x = jax.ShapeDtypeStruct((gf.DATA_SHARDS, 8 * MB), jnp.uint8,
-                             sharding=one_chip)
-    fn = jax.jit(lambda d: gp.gf256_matmul_pallas(consts, d,
-                                                  interpret=False))
-    _check(fn.lower(x).compile())
 
 
 @pytest.mark.parametrize("wm", [2048, 1000], ids=["wm2048", "ragged1000"])

@@ -15,7 +15,7 @@ Measures the costs ROADMAP items 2+3 target, before/after style:
   both against the originals; reports GB/s and the speedup.
 * **backend bake-off** (`--mode bakeoff`) — every available encoder
   backend (cpu-numpy / cpu-native / jax) runs batched encode, verify
-  and reconstruct over the same (B, 10, L) window blocks: encode GB/s
+  and reconstruct over the same blocks of B (10, L) windows: encode GB/s
   (wall-clock, informational), dispatches-per-GB batched vs
   per-window (deterministic), and a repair-bandwidth reconstruct row
   — gated on byte-identity against the per-window numpy oracle. Also
@@ -415,14 +415,26 @@ def backends() -> list:
         out.append(("cpu-native", CpuEncoder(use_native=True)))
     try:
         from seaweedfs_tpu.ec.encoder_jax import JaxEncoder
-        out.append(("jax", JaxEncoder(use_pallas=False)))
+        out.append(("jax", JaxEncoder()))
     except Exception as e:  # noqa: BLE001 — jax-less host: CPU rows only
         print(f"  (jax backend unavailable: {type(e).__name__}: {e})")
     return out
 
 
+def _shard_major(windows: np.ndarray) -> np.ndarray:
+    """(B, rows, L) windows -> the engine's shard-major (rows, R) block:
+    row i holds every window's row i back to back, zero-padded to the
+    words kernel's block quantum."""
+    from seaweedfs_tpu.ec.batch import _words_len
+
+    bsz, rows, n = windows.shape
+    block = np.zeros((rows, _words_len(bsz * n)), np.uint8)
+    block[:, :bsz * n] = windows.transpose(1, 0, 2).reshape(rows, -1)
+    return block
+
+
 def bench_bakeoff(args, report: dict) -> None:
-    """Backend bake-off over identical (B, 10, L) window blocks:
+    """Backend bake-off over identical blocks of B windows of (10, L):
     encode GB/s + dispatches-per-GB + repair reconstruct row per
     backend, gated on byte-identity against the per-window numpy
     oracle (wall-clock informational, accounting deterministic)."""
@@ -433,49 +445,56 @@ def bench_bakeoff(args, report: dict) -> None:
     L = (64 if args.smoke else 512) * 1024      # window bytes
     reps = 2 if args.smoke else 5
     rng = np.random.default_rng(args.seed)
-    block = rng.integers(0, 256, (B, gf.DATA_SHARDS, L)).astype(np.uint8)
-    bytes_in = block.nbytes
+    windows = rng.integers(0, 256, (B, gf.DATA_SHARDS, L)).astype(np.uint8)
+    block = _shard_major(windows)
+    singles = [_shard_major(windows[b:b + 1]) for b in range(B)]
+    bytes_in = windows.nbytes
     # the oracle IS cpu-numpy by definition — no need to probe every
     # backend (and double-initialize jax) just to fetch it
     oracle = CpuEncoder(use_native=False)
     rows_backends = backends()
     # per-window numpy oracle: THE byte-identity gate
     want_parity = np.stack([
-        np.stack(oracle.encode(list(block[b]))[gf.DATA_SHARDS:])
+        np.stack(oracle.encode(list(windows[b]))[gf.DATA_SHARDS:])
         for b in range(B)])
-    full = np.concatenate([block, want_parity], axis=1)
+    full = np.concatenate([windows, want_parity], axis=1)
+    full_block = _shard_major(full)[:, :B * L]
     present = [0, 2, 3, 4, 5, 6, 7, 8, 10, 12]
     lost = [1, 9, 11, 13]
+    rec_coeff = gf.cached_shard_rows(tuple(lost), tuple(present))
+    rec_block = _shard_major(full[:, present, :])
+    parity = gf.parity_matrix()
     rows = {}
     for name, enc in rows_backends:
         # encode: batched (ONE dispatch per block) vs per-window
         stats: dict = {}
-        par = ecb.transform_block(enc, gf.parity_matrix(), block, stats)
-        assert np.array_equal(par, want_parity), \
+        par = ecb.transform_block_async(enc, parity, block, stats)()
+        assert stats == {"dispatches": 1, "batches": 1}, (name, stats)
+        assert all(np.array_equal(got[:B * L], want) for got, want in
+                   zip(par, full_block[gf.DATA_SHARDS:])), \
             f"{name}: batched encode differs from numpy oracle"
         t0 = time.perf_counter()
         for _ in range(reps):
-            np.asarray(enc.transform_batch(gf.parity_matrix(), block))
+            ecb.transform_block_async(enc, parity, block)()
         dt = (time.perf_counter() - t0) / reps
         t0 = time.perf_counter()
-        for b in range(B):
-            np.asarray(enc.transform_batch(
-                gf.parity_matrix(), block[b:b + 1]))
+        for single in singles:
+            ecb.transform_block_async(enc, parity, single)()
         dt_pw = time.perf_counter() - t0
         # verify: per-window verdicts out of one dispatch
-        bad = full.copy()
-        bad[B // 2, gf.DATA_SHARDS + 1, 7] ^= 0x40
-        verdicts = ecb.verify_block(enc, bad)
+        bad = full_block.copy()
+        bad[gf.DATA_SHARDS + 1, B // 2 * L + 7] ^= 0x40
+        verdicts = ecb.verify_block(enc, bad, B)
         assert verdicts == [i != B // 2 for i in range(B)], \
             (name, verdicts)
-        assert ecb.verify_block(enc, full) == [True] * B, name
+        assert ecb.verify_block(enc, full_block, B) == [True] * B, name
         # reconstruct (repair-bandwidth row): all 4 lost rows of every
         # window from one coefficient dispatch
         t0 = time.perf_counter()
-        rec = np.asarray(enc.reconstruct_batch(present, lost,
-                                               full[:, present, :]))
+        rec = ecb.transform_block_async(enc, rec_coeff, rec_block)()
         dt_rec = time.perf_counter() - t0
-        assert np.array_equal(rec, full[:, lost, :]), \
+        assert all(np.array_equal(got[:B * L], full_block[sid])
+                   for got, sid in zip(rec, lost)), \
             f"{name}: batched reconstruct differs from numpy oracle"
         rows[name] = {
             "encode_GBps": round(bytes_in / (1 << 30) / dt, 3),
@@ -513,10 +532,11 @@ def bench_crossover(args, report: dict) -> None:
     at which dispatching the recover transform to the device backend
     beats the host encoder. Informational — prints the measured value
     next to the default so the flag stays honest."""
+    from seaweedfs_tpu.ec.batch import transform_block_async
     from seaweedfs_tpu.ec.encoder_cpu import CpuEncoder
     try:
         from seaweedfs_tpu.ec.encoder_jax import JaxEncoder
-        dev = JaxEncoder(use_pallas=False)
+        dev = JaxEncoder()
     except Exception as e:  # noqa: BLE001 — no device backend: nothing
         # to cross over to
         print(f"  crossover: skipped (jax unavailable: {e})")
@@ -528,13 +548,13 @@ def bench_crossover(args, report: dict) -> None:
     rows = {}
     crossover = None
     for size in sizes:
-        blk = rng.integers(0, 256, (1, gf.DATA_SHARDS, size)
-                           ).astype(np.uint8)
+        blk = _shard_major(rng.integers(0, 256, (1, gf.DATA_SHARDS, size)
+                                        ).astype(np.uint8))
         times = {}
         for name, enc in (("cpu", host), ("jax", dev)):
-            np.asarray(enc.transform_batch(coeff, blk))   # warm/compile
+            transform_block_async(enc, coeff, blk)()   # warm/compile
             best = min(
-                _timed(lambda: np.asarray(enc.transform_batch(coeff, blk)))
+                _timed(lambda: transform_block_async(enc, coeff, blk)())
                 for _ in range(3))
             times[name] = best
         rows[size] = {k: round(v * 1e3, 3) for k, v in times.items()}
