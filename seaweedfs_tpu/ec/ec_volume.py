@@ -167,6 +167,8 @@ class EcVolume:
         self._ecx = SortedFileNeedleMap(self.base_name + ".ecx",
                                         writable=True)
         self._ecj = open(self.base_name + ".ecj", "ab")
+        # serialises .ecx lookups with the tombstone writes of deletes;
+        # reads gather and recover outside it
         self._lock = threading.RLock()
         self.shards: dict[int, object] = {}
         for sid in range(gf.TOTAL_SHARDS):
@@ -587,23 +589,27 @@ class EcVolume:
 
     def read_needle(self, needle_id: int, cookie: int | None = None) -> Needle:
         """Locate via .ecx, gather stripe intervals, parse + CRC-check
-        (ReadEcShardNeedle, store_ec.go:119-153). The one volume lock is
-        timed as two `ec` spans: `lock_wait` until it is held, `locked`
-        while it is."""
+        (ReadEcShardNeedle, store_ec.go:119-153). The volume lock covers
+        the .ecx lookup only, timed as two `ec` spans: `lock_wait` until
+        it is held, `locked` while it is. A delete tombstones under the
+        same lock, so a read that starts after a delete returned sees
+        it. The gather and any recover run outside it: shard reads are
+        position-independent preads, and the recover's shared state
+        (reconstruction cache, plan and coefficient caches) is safe
+        across threads."""
         with tracing.timed_lock(self._lock, "ec"):
             offset, size = self.find_needle(needle_id)
             if size == t.TOMBSTONE_FILE_SIZE:
                 raise NotFoundError(f"needle {needle_id:x} deleted")
-            record_len = t.actual_size(size, self.version)
-            intervals = locate_data(self.large_block, self.small_block,
-                                    self.dat_size, offset, record_len)
-            parts = []
-            for iv in intervals:
-                sid, soff = iv.to_shard_and_offset(self.large_block,
-                                                   self.small_block)
-                parts.append(self._read_shard_interval(sid, soff, iv.size))
-            blob = b"".join(parts)
-        n = Needle.from_bytes(blob, self.version)
+        record_len = t.actual_size(size, self.version)
+        intervals = locate_data(self.large_block, self.small_block,
+                                self.dat_size, offset, record_len)
+        parts = []
+        for iv in intervals:
+            sid, soff = iv.to_shard_and_offset(self.large_block,
+                                               self.small_block)
+            parts.append(self._read_shard_interval(sid, soff, iv.size))
+        n = Needle.from_bytes(b"".join(parts), self.version)
         if cookie is not None and n.cookie != cookie:
             raise NotFoundError(f"cookie mismatch for {needle_id:x}")
         return n

@@ -22,8 +22,9 @@ from __future__ import annotations
 import jax
 import numpy as np
 
-from ..ops.gf256_pallas import (bytes_to_words, gf256_words_transform,
-                                words_to_bytes)
+from ..ops.gf256_pallas import (_BLOCK_BYTES, _DEFAULT_BM, _LANES,
+                                bytes_to_words, gf256_stacked_transform,
+                                gf256_words_transform, words_to_bytes)
 from ..util import tracing
 from . import gf
 
@@ -44,10 +45,15 @@ class JaxEncoder:
         with this batch's kernel time (the reference overlaps nothing:
         its 256KB loop at ec_encoder.go:114-186 is serial).
 
+        Rows shorter than that quantum (recovers of short intervals)
+        go as one block instead (`_transform_small`).
+
         Host stages: `ec`/`h2d` (the views and their transfer),
         `ec`/`launch` (enqueueing the kernel) and, in the thunk,
         `ec`/`readback` (blocked on the device and the copy back)."""
         n = len(rows[0])
+        if n < _DEFAULT_BM * _BLOCK_BYTES:
+            return self._transform_small(coeff, rows, n)
         with tracing.start("ec", "h2d") as sp:
             words = [jax.device_put(bytes_to_words(r)) for r in rows]
             sp.nbytes = n * len(rows)
@@ -60,4 +66,34 @@ class JaxEncoder:
                 sp.nbytes = n * len(outs)
                 return [words_to_bytes(np.asarray(o), n).copy()
                         for o in outs]
+        return readback
+
+    @staticmethod
+    def _transform_small(coeff: np.ndarray, rows: list, n: int):
+        """transform_async for rows of n bytes below the block quantum:
+        the k rows, zero-padded to a power-of-two count of 4 KiB tiles
+        (at most 6 shapes), cross as ONE (1, k, wm, 128) block through
+        the stacked kernel and come back in one readback. k transfers
+        of a padded 128 KiB quantum each would move 32x the bytes of a
+        1 KB recover, and each gives the interpreter lock to another
+        thread of a server under concurrent reads."""
+        wm = max(8, 1 << (-(-n // _BLOCK_BYTES) - 1).bit_length())
+        with tracing.start("ec", "h2d") as sp:
+            block = np.zeros((1, len(rows), wm * _BLOCK_BYTES), np.uint8)
+            for i, r in enumerate(rows):
+                block[0, i, :n] = (r if isinstance(r, np.ndarray)
+                                   else np.frombuffer(r, np.uint8))
+            x = jax.device_put(
+                block.view(np.uint32).reshape(1, len(rows), wm, _LANES))
+            sp.nbytes = n * len(rows)
+        with tracing.start("ec", "launch") as sp:
+            out = gf256_stacked_transform(gf.bitplane_constants(coeff), x)
+            sp.nbytes = n * len(rows)
+
+        def readback() -> list[np.ndarray]:
+            with tracing.start("ec", "readback") as sp:
+                o = np.asarray(out)
+                sp.nbytes = n * o.shape[1]
+                return [words_to_bytes(o[0, r], n).copy()
+                        for r in range(o.shape[1])]
         return readback

@@ -165,7 +165,9 @@ def gf256_stacked_transform(consts: np.ndarray, x: jax.Array,
     """Batched fast path: (B, k, wm, 128) uint32 -> (B, rows, wm, 128).
 
     One pallas_call carries a whole batch of volumes (grid = B x wm/bm);
-    the rack-encode mesh path calls this per-device inside shard_map.
+    the rack-encode mesh path calls this per-device inside shard_map, and
+    JaxEncoder sends rows shorter than the block quantum through it as
+    one (1, k, wm, 128) block.
     """
     consts = np.ascontiguousarray(consts, dtype=np.uint8)
     rows, k, _ = consts.shape

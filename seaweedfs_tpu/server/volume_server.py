@@ -2530,15 +2530,24 @@ class VolumeServer:
                         return web.json_response(
                             {"error": f"fetch {ext} from {source}: "
                                       f"{resp.status}"}, status=502)
+                    # a mounted EcVolume maps its .ecx, and truncating a
+                    # mapped file faults its readers: the index lands by
+                    # rename, and the mount that follows maps the new one
+                    path = base + ext + (".fetch" if ext == ".ecx" else "")
                     # shard files are volume-sized: file I/O off-loop
-                    f = await self._in_executor(open, base + ext, "wb")
+                    f = await self._in_executor(open, path, "wb")
                     try:
                         async for chunk in resp.content.iter_chunked(
                                 1 << 20):
                             await self._in_executor(f.write, chunk)
                     finally:
                         await self._in_executor(f.close)
+                    if path != base + ext:
+                        await self._in_executor(os.replace, path, base + ext)
             except (aiohttp.ClientError, OSError) as e:
+                if ext == ".ecx":
+                    await self._in_executor(self._unlink_quiet,
+                                            base + ".ecx.fetch")
                 return web.json_response({"error": str(e)}, status=502)
         return web.json_response({"ok": True})
 

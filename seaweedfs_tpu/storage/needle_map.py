@@ -14,6 +14,7 @@ and the EC .ecx index (needle_map_sorted_file.go).
 from __future__ import annotations
 
 import io
+import mmap
 import os
 import struct
 from dataclasses import dataclass
@@ -22,6 +23,7 @@ from typing import Callable, Iterator
 from . import types as t
 
 _ENTRY4 = struct.Struct(">QII")  # key, offset/8, size (4-byte offsets)
+_KEY = struct.Struct(">Q")  # an entry's leading key, at either width
 
 
 def pack_entry(key: int, actual_offset: int, size: int) -> bytes:
@@ -361,9 +363,17 @@ class SortedFileNeedleMap:
     """Binary search over a sorted 16B-entry index (.sdx/.ecx).
 
     Reference: needle_map_sorted_file.go, ec_volume.go:203-228
-    (SearchNeedleFromSortedIndex). Open writable for the EC delete path,
-    which tombstones entries in place (MarkNeedleDeleted,
-    ec_volume_delete.go:13-25).
+    (SearchNeedleFromSortedIndex). The search reads each probe's key by
+    offset from a read-only shared mmap of the file, as the reference
+    reads it with ReadAt: a lookup makes no system call, so a caller
+    that holds a lock across it never gives up the GIL while holding
+    it. Open writable for the EC delete path, which tombstones entries
+    in place through the file (MarkNeedleDeleted,
+    ec_volume_delete.go:13-25); the shared mapping sees the write.
+
+    The file must not be truncated in place while mapped: a probe past
+    the new end faults the process. Writers replace an index by
+    rename (write_sorted_index).
     """
 
     def __init__(self, path: str, writable: bool = False):
@@ -373,17 +383,18 @@ class SortedFileNeedleMap:
         self._size = os.path.getsize(path)
         assert self._size % t.NEEDLE_MAP_ENTRY_SIZE == 0, path
         self.count = self._size // t.NEEDLE_MAP_ENTRY_SIZE
-
-    def _entry(self, i: int) -> tuple[int, int, int]:
-        self._f.seek(i * t.NEEDLE_MAP_ENTRY_SIZE)
-        return unpack_entry(self._f.read(t.NEEDLE_MAP_ENTRY_SIZE))
+        # mmap refuses a zero-length file; an empty index has no probes
+        self._map = (mmap.mmap(self._f.fileno(), self._size,
+                               access=mmap.ACCESS_READ)
+                     if self._size else b"")
 
     def locate(self, key: int) -> int | None:
         """Entry index of key, or None."""
+        m, step, unpack = self._map, t.NEEDLE_MAP_ENTRY_SIZE, _KEY.unpack_from
         lo, hi = 0, self.count - 1
         while lo <= hi:
             mid = (lo + hi) // 2
-            k, _, _ = self._entry(mid)
+            k = unpack(m, mid * step)[0]
             if k == key:
                 return mid
             if k < key:
@@ -397,7 +408,7 @@ class SortedFileNeedleMap:
         i = self.locate(key)
         if i is None:
             return None
-        _, off, size = self._entry(i)
+        _, off, size = unpack_entry(self._map, i * t.NEEDLE_MAP_ENTRY_SIZE)
         return off, size
 
     def get(self, key: int) -> NeedleValue | None:
@@ -419,6 +430,8 @@ class SortedFileNeedleMap:
         return True
 
     def close(self) -> None:
+        if isinstance(self._map, mmap.mmap):
+            self._map.close()
         self._f.close()
 
 
@@ -427,11 +440,15 @@ def write_sorted_index(entries: list[tuple[int, int, int]], path: str) -> None:
 
     Last entry per key wins (matching WriteSortedFileFromIdx semantics,
     ec_encoder.go:26-50: deleted needles keep their tombstone size).
+    The file lands by rename, never truncated in place: a mounted
+    SortedFileNeedleMap may have the old one mapped.
     """
     latest: dict[int, tuple[int, int]] = {}
     for key, off, size in entries:
         latest[key] = (off, size)
-    with open(path, "wb") as f:
+    tmp = path + ".tmp"
+    with open(tmp, "wb") as f:
         for key in sorted(latest):
             off, size = latest[key]
             f.write(pack_entry(key, off, size))
+    os.replace(tmp, path)

@@ -848,8 +848,9 @@ def test_bulk_paths_launch_word_rows(tmp_path, monkeypatch, path):
 
 def test_concurrent_degraded_reads_time_the_lock(ec_fixture, fresh_tracing):
     """Two concurrent read_needle calls on one EcVolume: each records
-    `lock_wait` then `locked`; the call that comes second waits for the
-    first's hold. A recover's own stages nest under `ec`/`recover`."""
+    `lock_wait` then `locked`. The lock covers the .ecx lookup only: the
+    call that comes second waits for the first's lookup, and not for
+    its shard reads. A recover's own stages nest under `ec`/`recover`."""
     import threading
 
     from seaweedfs_tpu.ec.encoder_jax import JaxEncoder
@@ -857,38 +858,50 @@ def test_concurrent_degraded_reads_time_the_lock(ec_fixture, fresh_tracing):
     ev = EcVolume(d, "", 5, large_block=LB, small_block=SB,
                   encoder=JaxEncoder())
     ev.shards.pop(0).close()
-    inner = ev._read_shard_interval
-    held = threading.Event()
-
-    def slow_read(sid, off, size):
-        held.set()
-        time.sleep(0.05)
-        return inner(sid, off, size)
-    ev._read_shard_interval = slow_read
     # past the needle test_ec_delete_journal tombstones in the fixture
     nids = sorted(contents)[-2:]
-    got = {}
 
-    def get(nid):
-        with fresh_tracing.start_root("volume", "read") as root:
-            got[nid] = (ev.read_needle(nid), root)
-    first = threading.Thread(target=get, args=(nids[0],))
-    first.start()
-    assert held.wait(5)
-    second = threading.Thread(target=get, args=(nids[1],))
-    second.start()
-    first.join()
-    second.join()
-    ev._read_shard_interval = inner
-    waits = []
-    for nid in nids:
-        n, root = got[nid]
-        assert n.data == contents[nid]
-        by_op = _ec_spans(root)
-        assert len(by_op["lock_wait"]) == len(by_op["locked"]) == 1
-        waits.append(by_op["lock_wait"][0]["dur_ms"])
-    assert waits[1] > 0 and waits[1] > waits[0]
-    assert waits[1] >= 20
+    def race(name, delay):
+        """Both reads, the second started once the first is inside the
+        slowed `name`; -> each read's (lock_wait, locked) ms."""
+        inner = getattr(ev, name)
+        held = threading.Event()
+
+        def slow(*args):
+            held.set()
+            time.sleep(delay)
+            return inner(*args)
+        setattr(ev, name, slow)
+        got = {}
+
+        def get(nid):
+            with fresh_tracing.start_root("volume", "read") as root:
+                got[nid] = (ev.read_needle(nid), root)
+        first = threading.Thread(target=get, args=(nids[0],))
+        first.start()
+        assert held.wait(5)
+        second = threading.Thread(target=get, args=(nids[1],))
+        second.start()
+        first.join()
+        second.join()
+        delattr(ev, name)
+        out = []
+        for nid in nids:
+            n, root = got[nid]
+            assert n.data == contents[nid]
+            by_op = _ec_spans(root)
+            assert len(by_op["lock_wait"]) == len(by_op["locked"]) == 1
+            out.append((by_op["lock_wait"][0]["dur_ms"],
+                        by_op["locked"][0]["dur_ms"]))
+        return out
+
+    # a slow lookup holds the lock: the second read waits for it
+    (wait0, held0), (wait1, _) = race("find_needle", 0.05)
+    assert wait1 > wait0 and wait1 >= 20
+    assert held0 >= 45
+    # slow shard reads run after the release: nobody waits for them
+    (_, held0), (wait1, held1) = race("_read_shard_interval", 0.2)
+    assert wait1 < 100 and held0 < 100 and held1 < 100
     # a recover of shard 0 on the device path: its stages nest under it
     needle = next(nid for nid in sorted(contents)[1:]
                   if any(iv.to_shard_and_offset(LB, SB)[0] == 0

@@ -4,14 +4,18 @@ cached, then holder-grouped remote rows; the planned decode is
 byte-identical to the naive first-k gather; plans are cached per
 missing-set and invalidated on shard mount/unmount; a failed batch
 gather refreshes the holder map ONCE (never per shard) before the
-per-shard fallback; and survivor rows fetched for one lost shard are
+per-shard fallback; survivor rows fetched for one lost shard are
 reused — not re-moved — when a second lost shard of the same stripe
-recovers."""
+recovers; and concurrent degraded reads gather and recover outside the
+volume lock while deletes stay linearizable."""
 
 import itertools
 import os
 import random
 import shutil
+import sys
+import threading
+import time
 
 import numpy as np
 import pytest
@@ -19,7 +23,9 @@ import pytest
 from seaweedfs_tpu.ec import gf
 from seaweedfs_tpu.ec import pipeline as pl
 from seaweedfs_tpu.ec.ec_volume import (EcVolume, EcVolumeError,
-                                        select_survivors)
+                                        NotFoundError, select_survivors)
+from seaweedfs_tpu.ec.locate import locate_data
+from seaweedfs_tpu.storage import types as t
 from seaweedfs_tpu.storage.needle import Needle
 from seaweedfs_tpu.storage.volume import Volume
 from seaweedfs_tpu.util.chunk_cache import EcRecoverCache
@@ -389,4 +395,151 @@ def test_cached_survivor_rows_not_refetched_for_second_lost_shard(
         # second recover: 4 local + 6 cached survivor rows -> 0 new bytes
         assert counters["bytes"] == moved_first
     finally:
+        ev.close()
+
+
+# ---------------------------------------------------------------------
+# concurrency: the volume lock covers the .ecx lookup only
+# ---------------------------------------------------------------------
+
+def _recovers(ev, nid, lost) -> bool:
+    """Whether reading needle `nid` touches a shard in `lost`."""
+    off, size = ev.find_needle(nid)
+    return any(iv.to_shard_and_offset(LB, SB)[0] in lost
+               for iv in locate_data(LB, SB, ev.dat_size, off,
+                                     t.actual_size(size, ev.version)))
+
+
+class _Rendezvous:
+    """Host encoder whose first `parties` transforms wait for one
+    another at a barrier (which breaks after a timeout)."""
+
+    def __init__(self, parties: int):
+        self.inner = pl.get_encoder("cpu")
+        self.barrier = threading.Barrier(parties, timeout=10)
+        self.left = parties
+        self.mu = threading.Lock()
+
+    def transform_async(self, coeff, rows):
+        with self.mu:
+            wait = self.left > 0
+            self.left -= 1
+        if wait:
+            self.barrier.wait()
+        return self.inner.transform_async(coeff, rows)
+
+
+def test_concurrent_degraded_reads_recover_in_parallel(ec_dir):
+    """Two reads that each recover a lost-shard interval overlap: each
+    recover's transform waits at a two-party barrier, which both reach
+    only if neither read holds the volume lock through its recover.
+    Both bodies come back byte-equal to what was written."""
+    d, base, contents = ec_dir
+    os.remove(base + pl.to_ext(0))
+    ev = EcVolume(d, "", 9, large_block=LB, small_block=SB,
+                  encoder=_Rendezvous(2))
+    try:
+        nids = [n for n in sorted(contents) if _recovers(ev, n, {0})][:2]
+        assert len(nids) == 2
+        got, errors = {}, []
+
+        def read(nid):
+            try:
+                got[nid] = ev.read_needle(nid, nid * 7).data
+            except BaseException as e:  # noqa: BLE001 — reported below
+                errors.append(e)
+        threads = [threading.Thread(target=read, args=(n,)) for n in nids]
+        for th in threads:
+            th.start()
+        for th in threads:
+            th.join(30)
+        assert not errors, errors
+        assert got == {n: contents[n] for n in nids}
+        assert ev.recover_counts["host"] >= 2
+    finally:
+        ev.close()
+
+
+class _Counting:
+    """Host encoder that counts its transforms."""
+
+    def __init__(self):
+        self.inner = pl.get_encoder("cpu")
+        self.calls = 0
+        self.mu = threading.Lock()
+
+    def transform_async(self, coeff, rows):
+        with self.mu:
+            self.calls += 1
+        return self.inner.transform_async(coeff, rows)
+
+
+def test_reads_racing_deletes_see_bytes_or_not_found(ec_dir):
+    """More reader threads than cores GET random needles (some
+    recovering the lost shard 0) while one thread deletes every third
+    needle, with a short switch interval. Each answer is the written
+    bytes or NotFoundError, and a read that starts after a delete
+    returned sees the delete; once the deleter is done every deleted
+    needle reads NotFoundError and every other its bytes. No recover
+    count is lost."""
+    d, base, contents = ec_dir
+    os.remove(base + pl.to_ext(0))
+    enc = _Counting()
+    ev = EcVolume(d, "", 9, large_block=LB, small_block=SB, encoder=enc)
+    ids = sorted(contents)
+    doomed = set(ids[::3])
+    deleted_at: dict[int, float] = {}
+    done = threading.Event()
+    bad: list = []
+    reads = [0] * ((os.cpu_count() or 1) + 2)
+
+    def reader(i):
+        rng = random.Random(i)
+        while not done.is_set():
+            nid = rng.choice(ids)
+            t0 = time.perf_counter()
+            reads[i] += 1
+            try:
+                data = ev.read_needle(nid, nid * 7).data
+            except NotFoundError:
+                if nid not in doomed:
+                    bad.append((nid, "not found"))
+                continue
+            except BaseException as e:  # noqa: BLE001 — reported below
+                bad.append((nid, repr(e)))
+                continue
+            if data != contents[nid]:
+                bad.append((nid, "other bytes"))
+            elif t0 > deleted_at.get(nid, float("inf")):
+                bad.append((nid, "read after its delete returned"))
+
+    def deleter():
+        for nid in sorted(doomed):
+            ev.delete_needle(nid)
+            deleted_at[nid] = time.perf_counter()
+            time.sleep(0.002)
+
+    switch = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        readers = [threading.Thread(target=reader, args=(i,))
+                   for i in range(len(reads))]
+        for th in readers:
+            th.start()
+        deleter()
+        done.set()
+        for th in readers:
+            th.join(30)
+        assert not any(th.is_alive() for th in readers)
+        assert not bad, bad[:10]
+        assert min(reads) > 0
+        assert ev.recover_counts["host"] == enc.calls > 0
+        for nid in ids:
+            if nid in doomed:
+                with pytest.raises(NotFoundError):
+                    ev.read_needle(nid, nid * 7)
+            else:
+                assert ev.read_needle(nid, nid * 7).data == contents[nid]
+    finally:
+        sys.setswitchinterval(switch)
         ev.close()

@@ -123,3 +123,31 @@ def test_stacked_transform_matches_oracle():
             want = cpu.encode(list(data[v]))[10:]
             for p in range(4):
                 assert np.array_equal(got[v, p], want[p]), (b, n, v, p)
+
+
+@pytest.mark.parametrize("n,as_bytes", [
+    (1, False), (1000, True), (4097, False), (128 * 1024 - 1, True),
+    (128 * 1024, False)])
+def test_transform_rows_either_side_of_the_quantum(rng, n, as_bytes,
+                                                   monkeypatch):
+    """A recover of shard 0 from ten survivors, byte-equal to the CPU
+    oracle on both sides of the 128 KiB block quantum, with rows given
+    as arrays or bytes. Below it the ten rows cross in one transfer
+    (one block); at it, one transfer a row."""
+    import jax
+    puts = []
+    put = jax.device_put
+
+    def counting_put(x, *a, **k):
+        puts.append(x)
+        return put(x, *a, **k)
+    monkeypatch.setattr(jax, "device_put", counting_put)
+    cpu = CpuEncoder()
+    shards = cpu.encode([rng.integers(0, 256, n).astype(np.uint8)
+                         for _ in range(10)])
+    use = list(range(1, 11))
+    rows = [shards[i].tobytes() if as_bytes else shards[i] for i in use]
+    out = JaxEncoder().transform_async(gf.shard_rows([0], use), rows)()
+    assert len(out) == 1 and out[0].dtype == np.uint8
+    assert np.array_equal(out[0], shards[0])
+    assert len(puts) == (1 if n < 128 * 1024 else 10)

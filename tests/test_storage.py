@@ -132,6 +132,56 @@ def test_sorted_file_map(tmp_path):
     sm.close()
 
 
+_SORTED_KEYS = list(range(5, 2000, 7))
+
+
+@pytest.mark.parametrize("case,probes", [
+    ("first", [_SORTED_KEYS[0]]),
+    ("last", [_SORTED_KEYS[-1]]),
+    ("absent", [0, 1, 2000, (1 << 64) - 1]),
+    ("between", [6, 11, 13, _SORTED_KEYS[-1] - 1]),
+    ("empty", [0, 5, (1 << 64) - 1]),
+    ("mark_deleted", [12, _SORTED_KEYS[0], _SORTED_KEYS[-1]]),
+    ("rewritten", [_SORTED_KEYS[0], _SORTED_KEYS[-1]]),
+])
+def test_sorted_file_map_over_mmap(tmp_path, case, probes):
+    """Lookups through the mapped index agree with a plain bisect over
+    the entries: first, last, absent and between-keys ids, an empty
+    .ecx, a tombstone written through the file, which the next get_raw
+    sees through the map, and an index rewritten (here emptied) while
+    mapped, which the open map still reads as it was."""
+    import bisect
+    path = str(tmp_path / "1.ecx")
+    keys = [] if case == "empty" else _SORTED_KEYS
+    entries = {k: (k * 64, 10 + k) for k in keys}
+    write_sorted_index([(k, *v) for k, v in entries.items()], path)
+
+    def plain(key):
+        i = bisect.bisect_left(keys, key)
+        return entries[key] if i < len(keys) and keys[i] == key else None
+
+    sm = SortedFileNeedleMap(path, writable=True)
+    try:
+        assert sm.count == len(keys)
+        if case == "mark_deleted":
+            assert not sm.mark_deleted(probes[0] + 1)   # absent key
+            for key in probes:
+                assert sm.mark_deleted(key)
+                entries[key] = (entries[key][0], t.TOMBSTONE_FILE_SIZE)
+                assert sm.get(key) is None
+        if case == "rewritten":
+            write_sorted_index([], path)
+        for key in probes + keys:
+            assert sm.get_raw(key) == plain(key), key
+    finally:
+        sm.close()
+    if case == "mark_deleted":
+        reopened = SortedFileNeedleMap(path)
+        assert [reopened.get_raw(k) for k in keys] == \
+            [plain(k) for k in keys]
+        reopened.close()
+
+
 def test_volume_write_read_delete(tmp_path):
     v = Volume(str(tmp_path), "", 1)
     n = Needle(cookie=0xAA, id=1, data=b"first")

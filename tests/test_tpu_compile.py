@@ -90,6 +90,20 @@ def test_stacked_transform_compiles(one_chip, wm):
     _check(fn.lower(x).compile())
 
 
+@pytest.mark.parametrize("wm", [8, 256])
+def test_small_recover_compiles(one_chip, wm):
+    # JaxEncoder's one-block path for rows below the block quantum: a
+    # recover of shard 0 from ten survivors, 4 KiB (a 1 KB interval)
+    # and 128 KiB tiles (its largest shape)
+    consts = gf.bitplane_constants(
+        gf.shard_rows([0], list(range(1, gf.DATA_SHARDS + 1))))
+    x = jax.ShapeDtypeStruct((1, gf.DATA_SHARDS, wm, 128), jnp.uint32,
+                             sharding=one_chip)
+    fn = jax.jit(lambda v: gp.gf256_stacked_transform(consts, v,
+                                                      interpret=False))
+    _check(fn.lower(x).compile())
+
+
 def test_described_chip_has_published_peaks(one_chip):
     # bench.py looks the HBM ceiling up by this kind and refuses an
     # unknown one: the described v5e must be in its table
