@@ -216,6 +216,18 @@ def _count_striped(block: str, nbytes: int) -> None:
         metrics.EC_STRIPE_BYTES.labels(block=block).inc(int(nbytes))
 
 
+def count_repair(stage: str, nbytes: int) -> None:
+    """SeaweedFS_ec_repair_bytes_total{stage}: `copied` bytes fetched
+    from another server for a repair, `rebuilt` shard bytes written by
+    `rebuild_ec_files`."""
+    try:
+        from ..stats import metrics
+    except ImportError:
+        return
+    if metrics.HAVE_PROMETHEUS:
+        metrics.EC_REPAIR_BYTES.labels(stage=stage).inc(int(nbytes))
+
+
 def _close_outputs(files) -> None:
     """Close written shard files inside an `ec`/`write` span: a close
     can block on write-back of what the writes left in the page cache
@@ -742,7 +754,8 @@ def rebuild_ec_files(base_name: str, encoder=None,
     `use` restricts WHICH present shards feed the reconstruction (the
     same route's validated clean-input set: the first-k-on-disk
     default could otherwise pick up a local shard the caller knows to
-    be rotten); None keeps the first-k default."""
+    be rotten); None keeps the first-k default. The shard bytes written
+    count in SeaweedFS_ec_repair_bytes_total{stage="rebuilt"}."""
     import time as _time
 
     encoder = encoder or get_encoder()
@@ -776,6 +789,8 @@ def rebuild_ec_files(base_name: str, encoder=None,
     else:
         _rebuild_rows(base_name, encoder, missing, use,
                       buffer_size, stats, batch_windows=batch_windows)
+    count_repair("rebuilt", len(missing)
+                 * os.path.getsize(base_name + to_ext(use[0])))
     if stats is not None:
         stats["seconds"] = stats.get("seconds", 0.0) + \
             (_time.perf_counter() - t0)

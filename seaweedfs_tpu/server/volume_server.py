@@ -2254,28 +2254,44 @@ class VolumeServer:
         (the ec.copy fetch shape); returns an error string or None.
         Streams into a temp file and renames only on a COMPLETE body:
         a source dying mid-stream must never leave a truncated shard
-        file that a later pass would mount or feed into a rebuild."""
+        file that a later pass would mount or feed into a rebuild. A
+        delete journal (.ecj) the source does not have is no error: it
+        is written on the first EC delete. Each fetch is an `ec`/`fetch`
+        span, and the bytes it lands count in
+        SeaweedFS_ec_repair_bytes_total{stage="copied"}."""
         tmp = base + ext + ".fetch"
-        try:
-            await failpoints.fail("volume.ec_copy.fetch")
-            async with self._http.get(
-                    tls.url(source, "/admin/file"),
-                    params={"volume": str(vid),
-                            "collection": collection, "ext": ext},
-                    timeout=aiohttp.ClientTimeout(total=600)) as resp:
-                if resp.status != 200:
-                    return f"fetch {ext} from {source}: {resp.status}"
-                f = await self._in_executor(open, tmp, "wb")
-                try:
-                    async for chunk in resp.content.iter_chunked(1 << 20):
-                        await self._in_executor(f.write, chunk)
-                finally:
-                    await self._in_executor(f.close)
-            await self._in_executor(os.replace, tmp, base + ext)
-            return None
-        except (aiohttp.ClientError, asyncio.TimeoutError, OSError) as e:
-            await self._in_executor(self._unlink_quiet, tmp)
-            return str(e)
+        with tracing.start("ec", "fetch", ext=ext) as sp:
+            try:
+                await failpoints.fail("volume.ec_copy.fetch")
+                async with self._http.get(
+                        tls.url(source, "/admin/file"),
+                        params={"volume": str(vid),
+                                "collection": collection, "ext": ext},
+                        timeout=aiohttp.ClientTimeout(total=600)) as resp:
+                    if resp.status == 404 and ext == ".ecj":
+                        sp.status = "404"
+                        return None
+                    if resp.status != 200:
+                        sp.status = str(resp.status)
+                        return f"fetch {ext} from {source}: {resp.status}"
+                    landed = 0
+                    f = await self._in_executor(open, tmp, "wb")
+                    try:
+                        async for chunk in resp.content.iter_chunked(
+                                1 << 20):
+                            await self._in_executor(f.write, chunk)
+                            landed += len(chunk)
+                    finally:
+                        await self._in_executor(f.close)
+                await self._in_executor(os.replace, tmp, base + ext)
+            except (aiohttp.ClientError, asyncio.TimeoutError,
+                    OSError) as e:
+                sp.status = "error"
+                await self._in_executor(self._unlink_quiet, tmp)
+                return str(e)
+            sp.nbytes = landed
+        ecpl.count_repair("copied", landed)
+        return None
 
     async def h_ec_rebuild_shard(self, req: web.Request) -> web.Response:
         """Rebuild-to-target shard placement — the autopilot's repair
@@ -2503,52 +2519,26 @@ class VolumeServer:
 
     async def h_ec_copy(self, req: web.Request) -> web.Response:
         """VolumeEcShardsCopy (volume_grpc_erasure_coding.go:100-148):
-        pull shard files (and optionally .ecx/.ecj) from a source server."""
+        pull shard files (and optionally .ecx/.ecj) from a source
+        server, each through `_fetch_shard_file`, so a file lands at
+        its name only whole: a mounted EcVolume maps its .ecx, and the
+        mount that follows a copy maps the new one."""
         q = req.query
         vid = int(q["volume"])
         collection = q.get("collection", "")
         source = q["source"]
         shard_ids = [int(x) for x in q.get("shards", "").split(",") if x]
-        copy_ecx = q.get("copy_ecx", "") == "1"
-        d = self.store.dirs[0]
         base = os.path.join(
-            d, f"{collection}_{vid}" if collection else str(vid))
+            self.store.dirs[0],
+            f"{collection}_{vid}" if collection else str(vid))
         exts = [ecpl.to_ext(sid) for sid in shard_ids]
-        if copy_ecx:
+        if q.get("copy_ecx", "") == "1":
             exts += [".ecx", ".ecj"]
         for ext in exts:
-            try:
-                await failpoints.fail("volume.ec_copy.fetch")
-                async with self._http.get(
-                        tls.url(source, "/admin/file"),
-                        params={"volume": str(vid),
-                                "collection": collection,
-                                "ext": ext}) as resp:
-                    if resp.status != 200:
-                        if ext == ".ecj":  # journal may not exist yet
-                            continue
-                        return web.json_response(
-                            {"error": f"fetch {ext} from {source}: "
-                                      f"{resp.status}"}, status=502)
-                    # a mounted EcVolume maps its .ecx, and truncating a
-                    # mapped file faults its readers: the index lands by
-                    # rename, and the mount that follows maps the new one
-                    path = base + ext + (".fetch" if ext == ".ecx" else "")
-                    # shard files are volume-sized: file I/O off-loop
-                    f = await self._in_executor(open, path, "wb")
-                    try:
-                        async for chunk in resp.content.iter_chunked(
-                                1 << 20):
-                            await self._in_executor(f.write, chunk)
-                    finally:
-                        await self._in_executor(f.close)
-                    if path != base + ext:
-                        await self._in_executor(os.replace, path, base + ext)
-            except (aiohttp.ClientError, OSError) as e:
-                if ext == ".ecx":
-                    await self._in_executor(self._unlink_quiet,
-                                            base + ".ecx.fetch")
-                return web.json_response({"error": str(e)}, status=502)
+            err = await self._fetch_shard_file(source, vid, collection,
+                                               ext, base)
+            if err is not None:
+                return web.json_response({"error": err}, status=502)
         return web.json_response({"ok": True})
 
     async def h_ec_delete_shards(self, req: web.Request) -> web.Response:

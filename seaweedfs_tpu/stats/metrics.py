@@ -81,6 +81,14 @@ if HAVE_PROMETHEUS:
         "SeaweedFS_ec_stripe_bytes_total",
         ".dat bytes striped into EC shards, by stripe block kind",
         ["block"], registry=REGISTRY)
+    # shard repair traffic: bytes landed on this server by shard and
+    # index fetches from other servers ("copied": /admin/ec/copy, the
+    # rebuild-to-target gather) and shard bytes written by
+    # pipeline.rebuild_ec_files ("rebuilt")
+    EC_REPAIR_BYTES = Counter(
+        "SeaweedFS_ec_repair_bytes_total",
+        "bytes moved and rebuilt by EC shard repair, by stage",
+        ["stage"], registry=REGISTRY)
     # tiered read caches (util/chunk_cache.py): one label per cache —
     # "needle" (volume hot needles), "chunk" (filer/s3/webdav whole
     # chunks), "ec_recover" (degraded-read reconstructions),

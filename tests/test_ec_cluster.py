@@ -6,14 +6,24 @@ in-proc cluster.
 """
 
 import asyncio
+import importlib.util
 import os
 import random
+import time
 
+import numpy as np
+import pytest
+from aiohttp import web
 from cluster_util import Cluster, run
 
 from seaweedfs_tpu.ec import gf
+from seaweedfs_tpu.ec import pipeline as pl
 from seaweedfs_tpu.shell.env import CommandEnv
 from seaweedfs_tpu.shell import ec_commands as ec
+from seaweedfs_tpu.stats import metrics
+from seaweedfs_tpu.util import tracing
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 async def _fill_volume(c: Cluster, n_files: int = 40) -> list[tuple[str, str, bytes]]:
@@ -251,4 +261,232 @@ def test_ec_verify_scrub_detects_bit_rot(tmp_path):
                                 bad = rep["bad_windows"]
                                 break
                 assert bad, "flipped byte not detected by any holder"
+    run(body())
+
+
+# ---------------------------------------------------------------------------
+# a volume server lost from a four-server EC cluster (ec.rebuild's gather)
+# ---------------------------------------------------------------------------
+
+
+def _reference():
+    """benchmark/reference.py, the plain RS(10,4) and stripe layout that
+    imports nothing of the program, loaded by path."""
+    path = os.path.join(ROOT, "benchmark", "reference.py")
+    spec = importlib.util.spec_from_file_location("ec_reference", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def _reference_shards(dat: bytes, large: int, small: int) -> list[bytes]:
+    """The 14 shard files the reference stripes and codes from `dat`."""
+    ref = _reference()
+    coder = ref.Coder()
+    arr = np.frombuffer(dat, np.uint8)
+    out = [bytearray() for _ in range(gf.TOTAL_SHARDS)]
+    for pos, block, _ in ref.stripe_rows(len(dat), large, small):
+        got = coder.shards(list(range(gf.TOTAL_SHARDS)),
+                           ref.row_streams(arr, pos, block, 1))
+        for sid in range(gf.TOTAL_SHARDS):
+            out[sid] += got[sid].tobytes()
+    return [bytes(b) for b in out]
+
+
+def _holdings(c: Cluster, vid: int) -> dict:
+    """Live server -> the shard ids of `vid` mounted on it."""
+    return {vs: sorted(vs.store.ec_volumes[vid].shards)
+            for vs in c.servers if vid in vs.store.ec_volumes}
+
+
+async def _encoded_cluster(c: Cluster, tmp_path):
+    """Fill, link each volume's .dat aside, ec.encode (spread 4/4/3/3
+    over the four servers) and keep every shard file as encode wrote
+    it. Returns (files, vids, dats, shards by (vid, sid))."""
+    files = await _fill_volume(c)
+    await c.heartbeat_all()
+    vids = sorted({int(f.split(",")[0]) for f, _, _ in files})
+    dats = {}
+    for vid in vids:
+        holder = next(vs for vs in c.servers if vid in vs.store.volumes)
+        dats[vid] = str(tmp_path / f"ref_{vid}.dat")
+        os.link(holder._base_name(vid, "ectest") + ".dat", dats[vid])
+    async with CommandEnv(c.master.url, c.http) as env:
+        await ec.ec_encode(env, collection="ectest", vids=vids)
+    shards = {}
+    for vid in vids:
+        spread = _holdings(c, vid)
+        assert sorted(len(s) for s in spread.values()) == [3, 3, 4, 4]
+        for vs, sids in spread.items():
+            for sid in sids:
+                with open(vs._base_name(vid, "ectest") + pl.to_ext(sid),
+                          "rb") as f:
+                    shards[vid, sid] = f.read()
+    return files, vids, dats, shards
+
+
+async def _lose_server(c: Cluster, victim) -> None:
+    """Stop `victim` and wait until the master has reaped it (3 pulses
+    without a heartbeat)."""
+    await victim.stop()
+    c.servers.remove(victim)
+    deadline = time.monotonic() + 10
+    async with CommandEnv(c.master.url, c.http) as env:
+        while any(n["url"] == victim.url for n in await env.list_nodes()):
+            assert time.monotonic() < deadline, "master never reaped it"
+            await asyncio.sleep(c.pulse)
+
+
+def _pick(c: Cluster, vid: int, data: int, parity: int):
+    """A server holding `data` data and `parity` parity shards of vid."""
+    return next(vs for vs, sids in _holdings(c, vid).items()
+                if sum(s < gf.DATA_SHARDS for s in sids) == data
+                and sum(s >= gf.DATA_SHARDS for s in sids) == parity)
+
+
+@pytest.mark.parametrize("loss", ["server_3data_1parity",
+                                  "server_2data_1parity", "parity_only"])
+def test_ec_rebuild_restores_a_lost_server(tmp_path, loss):
+    """(a) the server holding three data shards and one parity shard is
+    stopped and reaped; (b) one holding two data and one parity; (c) the
+    four parity shards alone are deleted (BASELINE config 3). ec.rebuild
+    gathers the survivors on one server and restores 14 of 14: every
+    shard equals the file ec.encode wrote and the reference's RS(10,4)
+    of the .dat, no borrowed copy stays on the rebuilder, and every
+    needle reads back through a live server."""
+    async def body():
+        async with Cluster(str(tmp_path), n_servers=4) as c:
+            files, vids, dats, shards = await _encoded_cluster(c, tmp_path)
+            vid = vids[0]
+            lost: dict[int, list[int]] = {}
+            if loss == "parity_only":
+                for vs, sids in _holdings(c, vid).items():
+                    par = [s for s in sids if s >= gf.DATA_SHARDS]
+                    vs.store.unmount_ec_shards(vid, par)
+                    for sid in par:
+                        os.remove(vs._base_name(vid, "ectest")
+                                  + pl.to_ext(sid))
+                lost[vid] = list(range(gf.DATA_SHARDS, gf.TOTAL_SHARDS))
+                await c.heartbeat_all()
+            else:
+                data = 3 if loss == "server_3data_1parity" else 2
+                victim = _pick(c, vid, data, 1)
+                for v in vids:
+                    lost[v] = _holdings(c, v).get(victim, [])
+                await _lose_server(c, victim)
+            async with CommandEnv(c.master.url, c.http) as env:
+                results = await ec.ec_rebuild(env, collection="ectest")
+            assert {r["volume"]: r["rebuilt"] for r in results} == \
+                {v: s for v, s in lost.items() if s}, results
+            await c.heartbeat_all()
+            async with CommandEnv(c.master.url, c.http) as env:
+                smap = await ec.ec_shard_map(env)
+            by_url = {vs.url: vs for vs in c.servers}
+            for v in vids:
+                assert sorted(smap[v]["shards"]) == \
+                    list(range(gf.TOTAL_SHARDS)), v
+                with open(dats[v], "rb") as f:
+                    want = _reference_shards(f.read(), c.ec_large_block,
+                                             c.ec_small_block)
+                for sid, urls in smap[v]["shards"].items():
+                    for url in urls:
+                        base = by_url[url]._base_name(v, "ectest")
+                        with open(base + pl.to_ext(sid), "rb") as f:
+                            got = f.read()
+                        assert got == shards[v, sid], (v, sid, url)
+                        assert got == want[sid], (v, sid, url)
+                # on disk, each live server holds what the map places
+                # there: the borrowed inputs went
+                for vs in c.servers:
+                    base = vs._base_name(v, "ectest")
+                    on_disk = [] if base is None else \
+                        pl.present_shards(base)
+                    placed = [s for s, urls in smap[v]["shards"].items()
+                              if vs.url in urls]
+                    assert on_disk == sorted(placed), vs.url
+            live = c.servers[0].url
+            for fid, _, data in files:
+                st, got = await c.get(fid, live)
+                assert st == 200 and got == data, fid
+    run(body())
+
+
+def test_repair_counters_match_the_bytes_moved(tmp_path):
+    """One ec.rebuild after a server loss: the repair counter's
+    `copied` delta is the bytes of every file the rebuilder fetched
+    (each survivor it lacked, with the .ecx), equal to the sum of the
+    `ec`/`fetch` spans' bytes, and its `rebuilt` delta the restored
+    shards' bytes."""
+    def counter(stage: str) -> float:
+        return metrics.REGISTRY.get_sample_value(
+            "SeaweedFS_ec_repair_bytes_total", {"stage": stage}) or 0.0
+
+    async def body():
+        tracing.init(sample=1.0, slow_ms=0.0)
+        async with Cluster(str(tmp_path), n_servers=4) as c:
+            _, vids, _, shards = await _encoded_cluster(c, tmp_path)
+            assert len(vids) == 1
+            vid = vids[0]
+            victim = _pick(c, vid, 3, 1)
+            await _lose_server(c, victim)
+            with tracing._lock:
+                seen = {s.span_id for s in tracing._ring}
+            before = {st: counter(st) for st in ("copied", "rebuilt")}
+            async with CommandEnv(c.master.url, c.http) as env:
+                [res] = await ec.ec_rebuild(env, collection="ectest")
+            rebuilder = next(vs for vs in c.servers
+                             if vs.url == res["node"])
+            base = rebuilder._base_name(vid, "ectest")
+            shard = len(shards[vid, 0])
+            # survivors it already held (mounted now, less the restored)
+            own = set(rebuilder.store.ec_volumes[vid].shards) \
+                - set(res["rebuilt"])
+            copies = gf.DATA_SHARDS - len(own)
+            assert copies > 0
+            ecx = os.path.getsize(base + ".ecx")
+            want_copied = copies * (shard + ecx)
+            assert counter("copied") - before["copied"] == want_copied
+            assert counter("rebuilt") - before["rebuilt"] == \
+                len(res["rebuilt"]) * shard == 4 * shard
+            with tracing._lock:
+                fetched = [s for s in tracing._ring
+                           if s.span_id not in seen
+                           and (s.tier, s.op) == ("ec", "fetch")]
+            assert sum(s.nbytes for s in fetched) == want_copied
+            assert sum(s.attrs["ext"] not in (".ecx", ".ecj")
+                       for s in fetched) == copies
+    run(body())
+
+
+def test_ec_copy_from_a_source_that_dies_mid_body(tmp_path):
+    """/admin/ec/copy from a source that sends a quarter of a shard
+    file's declared length and drops the connection: the copy fails and
+    leaves no file at the shard's name (nor its temp file)."""
+    async def die_mid_body(req: web.Request) -> web.StreamResponse:
+        resp = web.StreamResponse(headers={"Content-Length": str(1 << 20)})
+        await resp.prepare(req)
+        await resp.write(b"\x5a" * (256 << 10))
+        req.transport.close()
+        return resp
+
+    async def body():
+        app = web.Application()
+        app.router.add_get("/admin/file", die_mid_body)
+        runner = web.AppRunner(app)
+        await runner.setup()
+        site = web.TCPSite(runner, "127.0.0.1", 0)
+        await site.start()
+        port = site._server.sockets[0].getsockname()[1]
+        try:
+            async with Cluster(str(tmp_path), n_servers=1) as c:
+                vs = c.servers[0]
+                async with c.http.post(
+                        f"http://{vs.url}/admin/ec/copy",
+                        params={"volume": "7", "shards": "3",
+                                "source": f"127.0.0.1:{port}"}) as resp:
+                    assert resp.status == 502, await resp.text()
+                left = sorted(os.listdir(vs.store.dirs[0]))
+                assert not [n for n in left if n.startswith("7.ec03")], left
+        finally:
+            await runner.cleanup()
     run(body())
